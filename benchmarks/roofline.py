@@ -8,8 +8,10 @@ plus MODEL_FLOPS = 6·N·D (dense) or 6·N_active·D (MoE), the useful-compute
 ratio, the dominant bottleneck, and a one-line improvement note.
 
 HLO figures use the depth-extrapolated values (HLO cost analysis counts
-while-loop bodies once; see launch/dryrun.py).
-TPU v5e constants: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+while-loop bodies once; see launch/dryrun.py). The peaks come from
+``PEAKS``, keyed by the ``device_kind`` each record names (the kind of the
+devices the dry run compiled for); a kind that is not in the table raises
+instead of borrowing another chip's numbers.
 """
 
 from __future__ import annotations
@@ -18,9 +20,21 @@ import json
 import os
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+#: Published per-chip peaks by ``jax.Device.device_kind``.
+#: "TPU v5 lite" — Google Cloud documentation, "TPU v5e": 197 TFLOP/s
+#: bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_bytes_per_s": 1600e9 / 8},
+}
+
+
+def peaks(device_kind: Optional[str]) -> Dict[str, float]:
+    """The peak table row for a device kind; unknown kinds raise."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results", "dryrun.json")
 
@@ -50,14 +64,15 @@ def analyze(rec: Dict) -> Optional[Dict]:
         coll = rec.get("collectives", {}).get("total_bytes", 0)
     if flops is None or bts is None:
         return None
-    t_c = flops / PEAK_FLOPS
-    t_m = bts / HBM_BW
-    t_x = coll / ICI_BW
+    peak = peaks(rec.get("device_kind"))
+    t_c = flops / peak["flops"]
+    t_m = bts / peak["hbm_bytes_per_s"]
+    t_x = coll / peak["ici_bytes_per_s"]
     dom = max((t_c, "compute"), (t_m, "memory"), (t_x, "collective"))[1]
     mf = model_flops(rec)
     t_total = max(t_c, t_m, t_x)
     # achievable fraction of the compute roofline for USEFUL flops:
-    frac = (mf / PEAK_FLOPS) / t_total if t_total > 0 else 0.0
+    frac = (mf / peak["flops"]) / t_total if t_total > 0 else 0.0
     return {
         "arch": rec["arch"], "shape": rec["shape"], "chips": rec["chips"],
         "compute_s": t_c, "memory_s": t_m, "collective_s": t_x,

@@ -10,7 +10,7 @@ correctly (verified empirically under 8 forced host devices; see
 ``argsort``, ``top_k``, ``unique`` hit the same lowering.
 
 Lexical approximation: any sort-based op *textually inside* a function
-passed to ``shard_map`` (or ``shard_map_compat`` / ``Mesh.shard_map``)
+passed to ``shard_map`` (or ``shard_map_unchecked`` / ``Mesh.shard_map``)
 is flagged, shard-varying or not — a shard-invariant use is the rare
 case and takes a justified ``# repro: ignore[shardmap-sort]``. Functions
 the rule cannot resolve (parameters, attributes) are skipped; when the
@@ -27,7 +27,7 @@ from ..visitors import (is_test_path, qualname, resolve_func_arg, under,
                         walk_scope)
 
 #: callee qualnames (last component) that open a shard_map region
-_SHARD_MAP_NAMES = {"shard_map", "shard_map_compat", "_shard_map"}
+_SHARD_MAP_NAMES = {"shard_map", "shard_map_unchecked"}
 
 #: sort-lowering ops: flagged by trailing attribute path
 _SORT_SUFFIXES = ("sort", "argsort", "lexsort", "top_k", "unique",

@@ -25,28 +25,12 @@ from .dpp import SubsetBatch
 from .krk_picard import _alpha_beta, _subset_AC
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: older releases ship it as
-    jax.experimental.shard_map, and the replication-check kwarg was renamed
-    check_rep -> check_vma independently of the top-level promotion, so
-    probe the kwarg rather than tying it to where the symbol lives.
-
-    The one shard_map shim for the repo — ``repro.dpp.runtime`` imports it
-    from here (this module has no ``repro.dpp`` dependencies, so the
-    import is cycle-free in that direction)."""
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
-_shard_map = shard_map_compat      # internal spelling, kept for callers
+def shard_map_unchecked(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication check off: outputs declared
+    replicated are replicated by construction (psum'd statistics,
+    replicated factor updates). ``repro.dpp.runtime`` uses it too."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_distributed_krk_step(mesh: Mesh, data_axes=("data",),
@@ -87,7 +71,7 @@ def make_distributed_krk_step(mesh: Mesh, data_axes=("data",),
         n_global = jax.lax.psum(jnp.asarray(n_local, jnp.float32), data_axes)
         return A / n_global, C / n_global
 
-    shard_AC = _shard_map(
+    shard_AC = shard_map_unchecked(
         local_AC, mesh,
         in_specs=(spec_r, spec_r, spec_b, spec_b),
         out_specs=(spec_r, spec_r))
@@ -278,7 +262,7 @@ def make_distributed_krk_sweep(mesh: Mesh, schedule, data_axes=("data",),
             return L1n, L2n, jnp.minimum(a1, a2), bt1 + bt2
         return L1n, upd2(a_trial), a_trial, jnp.zeros((), jnp.int32)
 
-    sweep = _shard_map(
+    sweep = shard_map_unchecked(
         local_sweep, mesh,
         in_specs=(spec_r, spec_r, spec_b, spec_b, spec_r, spec_r),
         out_specs=(spec_r, spec_r, spec_r, spec_r))

@@ -1,7 +1,8 @@
 """Activation sharding constraints (GSPMD hints inside model code).
 
-Model code calls these unconditionally; they no-op when no mesh is active
-(single-device smoke tests) and otherwise pin the canonical layout:
+Model code calls these unconditionally; they no-op when no mesh is set
+(``jax.set_mesh``; single-device smoke tests) and otherwise pin the
+canonical layout:
 
     batch over ("pod","data");  heads / experts / ffn-hidden over "model".
 
@@ -15,12 +16,12 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.interpreters import pxla
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
-def current_mesh() -> Optional[jax.sharding.Mesh]:
-    m = pxla.thread_resources.env.physical_mesh
+def current_mesh() -> Optional[jax.sharding.AbstractMesh]:
+    """The mesh set by ``jax.set_mesh``, or None."""
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
 
 

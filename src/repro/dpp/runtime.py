@@ -41,7 +41,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import obs
-from ..core.distributed import shard_map_compat
+from ..core.distributed import shard_map_unchecked
 
 
 class Runtime:
@@ -165,9 +165,9 @@ class Mesh(Runtime):
 
     # -- placement primitives ------------------------------------------------
     def shard_map(self, f, in_specs, out_specs):
-        """``shard_map`` over this mesh (version-compat, replication checks
-        off — outputs declared replicated are replicated by construction)."""
-        return shard_map_compat(f, self.mesh, in_specs, out_specs)
+        """``shard_map`` over this mesh (replication checks off — outputs
+        declared replicated are replicated by construction)."""
+        return shard_map_unchecked(f, self.mesh, in_specs, out_specs)
 
     def data_sharding(self) -> NamedSharding:
         return NamedSharding(self.mesh, P(self.data_axes))

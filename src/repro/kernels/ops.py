@@ -1,9 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles padding to MXU-aligned shapes, VMEM-aware block-size selection, and
-the CPU fallback: on non-TPU backends the wrappers run the kernels in
-interpret mode (small shapes, tests) or dispatch to the jnp oracle (large
-shapes), so library code can call these unconditionally.
+Handles padding to the TPU's (8, 128) tiling and VMEM-aware block-size
+selection. On TPU every wrapper runs its kernel compiled — never in
+interpret mode. Off TPU the wrappers run the jnp reference; tests force a
+kernel (``force_pallas=True`` / ``backend="pallas"``) to run it in
+interpret mode. Every decision emits a ``kernels.<op>.<engine>`` counter,
+so a run can show which engine it compiled against.
 """
 
 from __future__ import annotations
@@ -16,9 +18,9 @@ import jax.numpy as jnp
 from .. import obs
 from . import ref
 from .kron_matvec import kron_matvec_pallas
-from .partial_trace import partial_trace_A_pallas, partial_trace_C_pallas
+from .partial_trace import partial_trace_pallas
 from .greedy_map import greedy_map_update_pallas
-from .phase2_select import canonical_pair, phase2_select_pallas
+from .phase2_select import canonical_pair, padded_dims, phase2_select_pallas
 
 _VMEM_BUDGET = 12 * 2 ** 20  # bytes we allow a single kernel tile set to claim
 
@@ -96,20 +98,16 @@ def kron_eigvec_batch(P1: jax.Array, P2: jax.Array, i: jax.Array,
 # phase-2 projection-DPP selection (sampling hot path)
 # ---------------------------------------------------------------------------
 
-def _phase2_block_n1(N1: int, Nr: int, k: int) -> int:
-    """Largest G1 tile that keeps the kernel's resident set (norms + Gr +
-    basis + one G1 tile) inside the VMEM budget, or 0 when the fixed
-    resident set alone cannot fit (callers fall back to the reference)."""
-    fixed = (N1 * Nr + Nr * k + 2 * k * k + k) * 4
-    if fixed + k * 4 > _VMEM_BUDGET:
-        return 0
-    bn1 = N1
-    while bn1 > 1 and fixed + bn1 * k * 4 > _VMEM_BUDGET:
-        bn1 = (bn1 + 1) // 2
-    return bn1
+def _phase2_fits(N1: int, Nr: int, k: int) -> bool:
+    """Whether the fused kernel's VMEM set fits the budget: the padded
+    factor pair (double-buffered), the basis, and the (N1p, Nrp) norms
+    grid plus the step's few same-shape temporaries."""
+    N1p, Nrp, K = padded_dims(N1, Nr, k)
+    need = (4 * (N1p + Nrp) * K + K * K + 6 * N1p * Nrp) * 4
+    return need <= _VMEM_BUDGET and N1 * Nr < 2 ** 24
 
 
-def phase2_select(us, Gs, sizes, k_eff, backend=None, block_n1=0):
+def phase2_select(us, Gs, sizes, k_eff, backend=None):
     """Projection-DPP phase-2 selection — the ops-level dispatch point.
 
     us:    (k_max,) or (B, k_max) per-step uniforms.
@@ -118,27 +116,25 @@ def phase2_select(us, Gs, sizes, k_eff, backend=None, block_n1=0):
     k_eff: () or (B,) int32 live step counts.
     Returns picks of shape us.shape, int32, -1 in padded/dead slots.
 
-    backend: None — auto (fused Pallas kernel on TPU, jax while_loop
-        reference elsewhere); "reference" — force the while_loop;
-        "pallas" — force the fused kernel (interpret mode off-TPU, the
-        honest CPU test/benchmark path).
-    Both backends run bit-identical arithmetic on the canonicalized
-    (G1, Gr) factor pair, so picks agree draw-for-draw on shared uniforms
+    backend: None — auto (fused Pallas kernel on TPU when its VMEM set
+        fits, jax while_loop reference otherwise — the choice shows in
+        the ``kernels.phase2_select.<engine>`` counter); "reference" —
+        force the while_loop; "pallas" — force the fused kernel
+        (interpret mode off-TPU, the CPU test path).
+    Both backends run one step function on the same padded (G1, Gr)
+    pair, so picks agree draw-for-draw on shared uniforms
     (property-tested in tests/test_phase2_fused.py).
     """
     got = tuple(int(G.shape[-2]) for G in Gs)
     if got != tuple(int(s) for s in sizes):
         raise ValueError(f"sizes {tuple(sizes)} inconsistent with the "
                          f"factor-column row counts {got}")
-    Nr = 1
-    for G in Gs[1:]:
-        Nr *= int(G.shape[-2])
-    auto_bn1 = block_n1 if block_n1 > 0 else _phase2_block_n1(
-        int(Gs[0].shape[-2]), Nr, int(us.shape[-1]))
+    N1, Nr = (1, got[0]) if len(got) == 1 else (got[0], 1)
+    for n in got[1:]:
+        Nr *= n
+    fits = _phase2_fits(N1, Nr, int(us.shape[-1]))
     if backend is None:
-        # auto never launches a kernel whose fixed resident set (norms +
-        # Gr fold + basis) cannot fit VMEM — the while_loop keeps working
-        backend = "pallas" if _on_tpu() and auto_bn1 > 0 else "reference"
+        backend = "pallas" if _on_tpu() and fits else "reference"
     k_eff = jnp.asarray(k_eff, jnp.int32)
     batched = us.ndim == 2
     if backend == "reference":
@@ -152,10 +148,10 @@ def phase2_select(us, Gs, sizes, k_eff, backend=None, block_n1=0):
     if backend != "pallas":
         raise ValueError(f"phase2_select backend must be None, 'reference' "
                          f"or 'pallas', got {backend!r}")
-    if auto_bn1 <= 0:
+    if not fits:
         raise ValueError(
             f"phase2_select fused kernel needs its resident set (norms "
-            f"N1*Nr={Gs[0].shape[-2]}*{Nr}, Gr fold, basis) inside the "
+            f"N1*Nr={N1}*{Nr}, factor pair, basis) inside the "
             f"{_VMEM_BUDGET >> 20}MiB VMEM budget; use "
             f"backend='reference' for this shape")
     _count_dispatch("phase2_select", "pallas")
@@ -163,8 +159,7 @@ def phase2_select(us, Gs, sizes, k_eff, backend=None, block_n1=0):
         Gs = tuple(G[None] for G in Gs)
         us, k_eff = us[None], k_eff[None]
     G1, Gr = canonical_pair(Gs)
-    picks = phase2_select_pallas(us, k_eff, G1, Gr, block_n1=auto_bn1,
-                                 interpret=not _on_tpu())
+    picks = phase2_select_pallas(us, k_eff, G1, Gr, interpret=not _on_tpu())
     return picks if batched else picks[0]
 
 
@@ -172,67 +167,86 @@ def phase2_select(us, Gs, sizes, k_eff, backend=None, block_n1=0):
 # partial traces (KrK-Picard batch route)
 # ---------------------------------------------------------------------------
 
+def _partial_trace_pallas(theta4: jax.Array, M: jax.Array) -> jax.Array:
+    """out[k,l] = Σ_{u,v} theta4[k,u,l,v] M[v,u] on the kernel. On TPU the
+    trailing size pads to the 128-lane tiling (zero blocks add nothing)
+    and the (bk, bl) tile is the largest divisor pair of the leading size
+    whose working set — the Θ tile double-buffered, the resident M tiling
+    and the product — fits the VMEM budget."""
+    n, m = theta4.shape[0], theta4.shape[1]
+    mp = _round_up(m, 128) if _on_tpu() else m
+    if mp != m:
+        theta4 = jnp.pad(theta4, ((0, 0), (0, mp - m), (0, 0), (0, mp - m)))
+        M = jnp.pad(M, ((0, mp - m), (0, mp - m)))
+    block = 5 * mp * mp * 4
+    divs = [d for d in range(1, n + 1) if n % d == 0]
+    bk = max(d for d in divs if d * d * block <= _VMEM_BUDGET or d == 1)
+    bl = max(d for d in divs if bk * d * block <= _VMEM_BUDGET or d == 1)
+    return partial_trace_pallas(theta4, M, bk=bk, bl=bl,
+                                interpret=not _on_tpu())
+
+
 def partial_trace_A(theta: jax.Array, L2: jax.Array, N1: int, N2: int,
                     force_pallas: bool = False) -> jax.Array:
+    """A[k,l] = Σ_{u,v} Θ4[k,u,l,v] L2[v,u], (N1, N1)."""
     theta4 = theta.reshape(N1, N2, N1, N2)
     if not (_on_tpu() or force_pallas):
         _count_dispatch("partial_trace_A", "reference")
         return ref.partial_trace_A_ref(theta4, L2)
     _count_dispatch("partial_trace_A", "pallas")
-    bk = bl = 1
-    while bk < N1 and N1 % (bk * 2) == 0 and (2 * bk) * bl * N2 * N2 * 4 <= _VMEM_BUDGET:
-        bk *= 2
-    while bl < N1 and N1 % (bl * 2) == 0 and bk * (2 * bl) * N2 * N2 * 4 <= _VMEM_BUDGET:
-        bl *= 2
-    return partial_trace_A_pallas(theta4, L2, bk=bk, bl=bl,
-                                  interpret=not _on_tpu())
+    return _partial_trace_pallas(theta4, L2)
 
 
 def partial_trace_C(theta: jax.Array, L1: jax.Array, N1: int, N2: int,
                     force_pallas: bool = False) -> jax.Array:
+    """C[u,v] = Σ_{i,j} L1[i,j] Θ4[i,u,j,v], (N2, N2). The kernel runs it
+    as the A contraction on Θ with its factor axes swapped, against L1ᵀ."""
     theta4 = theta.reshape(N1, N2, N1, N2)
     if not (_on_tpu() or force_pallas):
         _count_dispatch("partial_trace_C", "reference")
         return ref.partial_trace_C_ref(theta4, L1)
     _count_dispatch("partial_trace_C", "pallas")
-    bu = bv = 1
-    while bu < N2 and N2 % (bu * 2) == 0 and (2 * bu) * bv * N1 * N1 * 4 <= _VMEM_BUDGET:
-        bu *= 2
-    while bv < N2 and N2 % (bv * 2) == 0 and bu * (2 * bv) * N1 * N1 * 4 <= _VMEM_BUDGET:
-        bv *= 2
-    return partial_trace_C_pallas(theta4, L1, bu=bu, bv=bv,
-                                  interpret=not _on_tpu())
+    return _partial_trace_pallas(theta4.transpose(1, 0, 3, 2), L1.T)
 
 
 # ---------------------------------------------------------------------------
 # greedy MAP (k-DPP) built on the Pallas step kernel
 # ---------------------------------------------------------------------------
 
-def greedy_map_update(lcol, C, cj, dj, d, force_pallas: bool = False):
+def greedy_map_update(lcol, Ct, cj, dj, d, force_pallas: bool = False):
+    """One fast-greedy update: lcol (N,), transposed Cholesky buffer
+    Ct (k, N), its chosen column cj (k,), dj (1,), d (N,) ->
+    (e, d_new), each (N,) f32. The kernel path needs N a multiple of 128
+    (``greedy_map_kdpp`` pads its state once)."""
     if not (_on_tpu() or force_pallas):
         _count_dispatch("greedy_map_update", "reference")
-        return ref.greedy_map_update_ref(lcol, C, cj, dj, d)
+        return ref.greedy_map_update_ref(lcol, Ct, cj, dj, d)
     _count_dispatch("greedy_map_update", "pallas")
-    N = d.shape[0]
-    bn = min(512, N)
-    while N % bn != 0:
-        bn //= 2
-    return greedy_map_update_pallas(lcol, C, cj, dj, d, block_n=bn,
-                                    interpret=not _on_tpu())
+    k, N = Ct.shape
+    bn = 128     # per step: k Ct rows + 4 vectors, double-buffered, f32
+    while N % (2 * bn) == 0 and (k + 4) * 2 * (2 * bn) * 4 <= _VMEM_BUDGET:
+        bn *= 2
+    e, d_new = greedy_map_update_pallas(lcol[None], Ct, cj[:, None],
+                                        dj[None], d[None], block_n=bn,
+                                        interpret=not _on_tpu())
+    return e[0], d_new[0]
 
 
 def greedy_map_kdpp(L: jax.Array, k: int, force_pallas: bool = False) -> jax.Array:
     """Full greedy MAP selection of k items using the step kernel.
 
     Equivalent to core.sampling.greedy_map_kdpp; this version routes the
-    O(Nk) inner update through the Pallas kernel.
+    O(Nk) inner update through the Pallas kernel. The state is padded to
+    a lane-aligned Np = round_up(N, 128) once: padded items start chosen
+    with zero variance, so they are never picked and the per-step kernel
+    call needs no padding copy.
     """
     N = L.shape[0]
-
+    Np = _round_up(N, 128)
     eps = ref.degeneracy_eps(L)
 
     def body(state, t):
-        d, C, chosen = state
+        d, Ct, chosen = state
         scores = jnp.where(chosen, -jnp.inf, d)
         j = jnp.argmax(scores)
         # Degenerate conditional variance (k beyond numerical rank): a raw
@@ -240,16 +254,17 @@ def greedy_map_kdpp(L: jax.Array, k: int, force_pallas: bool = False) -> jax.Arr
         # Clamp the divisor and zero the update so the pick stays a valid
         # index and the remaining state is untouched.
         ok = d[j] > eps
+        lcol = jnp.pad(L[:, j].astype(jnp.float32), (0, Np - N))
         e, d_upd = greedy_map_update(
-            L[:, j], C, C[j], jnp.maximum(d[j], eps)[None], d,
+            lcol, Ct, Ct[:, j], jnp.maximum(d[j], eps)[None], d,
             force_pallas=force_pallas)
         e = jnp.where(ok, e, 0.0)
         d_new = jnp.where(ok, jnp.maximum(d_upd, 0.0), d)
-        C_new = jax.lax.dynamic_update_index_in_dim(C.T, e, t, axis=0).T
-        return (d_new, C_new, chosen.at[j].set(True)), j
+        Ct_new = jax.lax.dynamic_update_index_in_dim(Ct, e, t, axis=0)
+        return (d_new, Ct_new, chosen.at[j].set(True)), j
 
-    d0 = jnp.diagonal(L).astype(jnp.float32)
-    C0 = jnp.zeros((N, k), jnp.float32)
+    d0 = jnp.pad(jnp.diagonal(L).astype(jnp.float32), (0, Np - N))
+    Ct0 = jnp.zeros((k, Np), jnp.float32)
     (_, _, _), picks = jax.lax.scan(
-        body, (d0, C0, jnp.zeros((N,), bool)), jnp.arange(k))
+        body, (d0, Ct0, jnp.arange(Np) >= N), jnp.arange(k))
     return picks.astype(jnp.int32)

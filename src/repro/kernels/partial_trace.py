@@ -1,19 +1,25 @@
-"""Pallas TPU kernels for the Appendix-B partial-trace contractions:
+"""Pallas TPU kernel for the Appendix-B partial-trace contractions:
 
     A[k,l] = Tr(Θ_(kl) · L2) = Σ_{u,v} Θ4[k,u,l,v] L2[v,u]      (B.1)
     C[u,v] = Σ_{i,j} L1[i,j] Θ4[i,u,j,v]                        (B.2)
 
 These are the batch-mode hot spots of KrK-Picard once Θ is materialized
-(O(N²) data read exactly once → memory-bound; the kernel's job is to stream
-Θ HBM→VMEM in MXU-aligned tiles and never re-read it).
+(O(N²) data → memory-bound; the kernel's job is to stream Θ HBM→VMEM in
+tiling-aligned 2-D blocks).
 
-Tiling for A: grid (N1/bk, N1/bl); each step loads the Θ4 tile
-(bk, N2, bl, N2), reorders to (bk·bl, N2·N2) in VMEM, and contracts with
-vec(L2ᵀ) kept resident — one matvec per tile, fp32 accumulate.
+One kernel serves both. (B.2) is (B.1) on the transposed blocks:
+C = A'(Θ4.transpose(1, 0, 3, 2), L1ᵀ), so ``kernels.ops`` computes C by
+handing this kernel Θ with its factor axes swapped.
 
-VMEM (bk=bl=8, N2=256, fp32): tile 8·256·8·256·4B = 16MB... so defaults are
-(bk=bl=4, N2≤256 → 4MB) or (bk=bl=8, N2≤128 → 4MB); ops.py picks block sizes
-from a VMEM budget.
+Layout: Θ is the 2-D (N1·N2p, N1·N2p) matrix of N2p × N2p blocks, N2p the
+trailing factor size padded to the 128-lane tiling (zero rows/columns add
+nothing). Grid step (i, j) reads the (bk·N2p, bl·N2p) tile holding the
+blocks (k, l) ∈ [i·bk, (i+1)·bk) × [j·bl, (j+1)·bl), multiplies it
+elementwise by the resident tiling of L2ᵀ, and reduces each N2p × N2p block
+to a scalar with two indicator matmuls (E_r · S · E_c) at full f32
+precision. The (bk, bl) result lands in an (N1/bk, N1/bl, bk, bl) output
+whose block is the whole trailing pair of dims, so any bk, bl dividing N1
+is a legal block.
 """
 
 from __future__ import annotations
@@ -24,65 +30,46 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _kernel_A(theta_ref, w_ref, o_ref):
-    # theta tile: (bk, N2, bl, N2); w: (N2*N2,) = vec(L2.T)
-    t = theta_ref[...]
-    bk, n2, bl, _ = t.shape
-    t = t.transpose(0, 2, 1, 3).reshape(bk * bl, n2 * n2)
-    w = w_ref[...].reshape(n2 * n2, 1)
-    o = jax.lax.dot_general(t, w, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    o_ref[...] = o.reshape(bk, bl).astype(o_ref.dtype)
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _kernel_C(theta_ref, l1_ref, acc_ref):
-    # theta tile: (N1, bu, N1, bv) — full factor-1 dims; l1: (N1, N1)
-    t = theta_ref[...]
-    n1, bu, _, bv = t.shape
-    t = t.transpose(1, 3, 0, 2).reshape(bu * bv, n1 * n1)
-    w = l1_ref[...].reshape(n1 * n1, 1)
-    o = jax.lax.dot_general(t, w, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    acc_ref[...] = o.reshape(bu, bv).astype(acc_ref.dtype)
+def _kernel(theta_ref, w_ref, o_ref, *, n2p: int):
+    s = theta_ref[...] * w_ref[...]                # (bk·n2p, bl·n2p)
+    bk, bl = o_ref.shape[2], o_ref.shape[3]
+    er = (jax.lax.broadcasted_iota(jnp.int32, (bk, bk * n2p), 1) // n2p
+          == jax.lax.broadcasted_iota(jnp.int32, (bk, bk * n2p), 0))
+    ec = (jax.lax.broadcasted_iota(jnp.int32, (bl * n2p, bl), 0) // n2p
+          == jax.lax.broadcasted_iota(jnp.int32, (bl * n2p, bl), 1))
+    rows = jnp.dot(er.astype(jnp.float32), s, precision=_HIGHEST,
+                   preferred_element_type=jnp.float32)
+    o_ref[0, 0] = jnp.dot(rows, ec.astype(jnp.float32), precision=_HIGHEST,
+                          preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "bl", "interpret"))
-def partial_trace_A_pallas(theta4: jax.Array, L2: jax.Array,
-                           bk: int = 4, bl: int = 4,
-                           interpret: bool = False) -> jax.Array:
-    """theta4: (N1, N2, N1, N2) -> A: (N1, N1)."""
-    N1, N2 = theta4.shape[0], theta4.shape[1]
-    assert N1 % bk == 0 and N1 % bl == 0
-    w = L2.T.reshape(-1)
-    return pl.pallas_call(
-        _kernel_A,
+def partial_trace_pallas(theta4: jax.Array, M: jax.Array, bk: int = 1,
+                         bl: int = 1, interpret: bool = False) -> jax.Array:
+    """A[k,l] = Σ_{u,v} theta4[k,u,l,v] M[v,u].
+
+    theta4: (N1, N2p, N1, N2p) with N2p a multiple of 128 (or the whole
+    trailing size, e.g. under interpret); M: (N2p, N2p); bk, bl divide N1.
+    Returns A: (N1, N1) f32.
+    """
+    N1, N2p = theta4.shape[0], theta4.shape[1]
+    if N1 % bk or N1 % bl:
+        raise ValueError(f"tiles ({bk}, {bl}) must divide N1={N1}")
+    theta = theta4.reshape(N1 * N2p, N1 * N2p).astype(jnp.float32)
+    w = jnp.tile(M.T.astype(jnp.float32), (bk, bl))
+    out = pl.pallas_call(
+        functools.partial(_kernel, n2p=N2p),
         grid=(N1 // bk, N1 // bl),
         in_specs=[
-            pl.BlockSpec((bk, N2, bl, N2), lambda i, j: (i, 0, j, 0)),
-            pl.BlockSpec((N2 * N2,), lambda i, j: (0,)),
+            pl.BlockSpec((bk * N2p, bl * N2p), lambda i, j: (i, j)),
+            pl.BlockSpec((bk * N2p, bl * N2p), lambda i, j: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bk, bl), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((N1, N1), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, bk, bl), lambda i, j: (i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N1 // bk, N1 // bl, bk, bl),
+                                       jnp.float32),
         interpret=interpret,
-    )(theta4, w)
-
-
-@functools.partial(jax.jit, static_argnames=("bu", "bv", "interpret"))
-def partial_trace_C_pallas(theta4: jax.Array, L1: jax.Array,
-                           bu: int = 4, bv: int = 4,
-                           interpret: bool = False) -> jax.Array:
-    """theta4: (N1, N2, N1, N2) -> C: (N2, N2)."""
-    N1, N2 = theta4.shape[0], theta4.shape[1]
-    assert N2 % bu == 0 and N2 % bv == 0
-    return pl.pallas_call(
-        _kernel_C,
-        grid=(N2 // bu, N2 // bv),
-        in_specs=[
-            pl.BlockSpec((N1, bu, N1, bv), lambda i, j: (0, i, 0, j)),
-            pl.BlockSpec((N1, N1), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bu, bv), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((N2, N2), jnp.float32),
-        interpret=interpret,
-    )(theta4, L1)
+    )(theta, w)
+    return out.transpose(0, 2, 1, 3).reshape(N1, N1)

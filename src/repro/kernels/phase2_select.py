@@ -2,39 +2,46 @@
 
 The reference implementation (``sampling.batched.phase2_select_reference``)
 runs the Gram-Schmidt chain rule as a ``lax.while_loop`` of O(k_eff) small
-ops — cumsum -> inverse-CDF search -> factored row gather -> CGS2 -> one
-O(N) colspace matvec -> norms downdate — re-reading the factored columns
-and the residual-norms vector from HBM every step. This kernel fuses the
-whole loop into one ``pallas_call``:
+ops — prefix mass -> inverse-CDF search -> factored row gather -> CGS2 ->
+one O(N) colspace matvec -> norms downdate — re-reading the factored
+columns and the residual-norms vector from HBM every step. This kernel
+fuses the whole loop into one ``pallas_call``:
 
-grid        (batch, k_max, 2, n_tiles) — sequential on TPU, so VMEM/SMEM
-            scratch carries state across steps. Dims: sample b, selection
-            step t, phase p (0 = norms init/downdate, 1 = select), and the
-            N1-tile j streaming the leading-factor block.
-resident    the (k_max, k_max) Gram-Schmidt basis B, the (N1, Nr) residual
-            norms, the gathered row w, and the {alive, pick} scalars live
-            in scratch for all k_eff steps — only the G1 tiles stream.
+grid        (batch,) — one sample per grid step; inside it a
+            ``lax.while_loop`` runs the data-dependent k_eff selection
+            steps (k_eff arrives by scalar prefetch into SMEM).
+resident    the factor pair, the (K, K) Gram-Schmidt basis (one basis row
+            per step), and the (N1p, Nrp) residual norms live in VMEM for
+            all k_eff steps.
 factors     canonicalized to exactly two: the leading block G1 (N1, k) and
             the elementwise-product fold Gr (Nr, k) of every trailing
-            factor (``canonical_pair``); m = 1 gets a ones() second factor.
+            factor (``canonical_pair``); m = 1 gets a ones() leading factor.
             One kernel therefore serves the DPP, k-DPP and dense paths.
+layout      ``pad_pair`` pads N1 and k to the 8-row sublane tiling and Nr
+            to the 128-lane tiling with zeros: padded items
+            carry zero mass and are never selected, padded columns add
+            exact zeros to every product.
 
-Phase 0 initializes norms[n] = sum_c prod_f G_f[n_f, c]^2 (t = 0) or
-applies the downdate norms -= (V q_{t-1})^2 tile-by-tile off B's column
-t-1. Phase 1 draws the inverse-CDF index off the full resident norms
-cumsum (identical arithmetic to the reference — the property tests assert
-draw-for-draw equality), gathers the factored row from the owning tile,
-runs CGS2 in the k-dimensional coefficient space, and writes the pick.
+Both engines run one step function, ``select_step``, on the same padded
+arrays, so on the CPU their picks agree draw for draw (the property tests
+assert equality). The step is written in the operations Mosaic lowers:
+the row-major prefix mass is a Hillis-Steele scan of ``pltpu.roll`` shifts
+(adds in a fixed order — no cumsum), the inverse-CDF index is a masked
+min over an index grid, rows are gathered and written with iota masks
+(no dynamic single-lane stores), and every dot runs at HIGHEST precision.
+
+Inverse CDF: the pick is the first positive-mass item whose prefix mass
+exceeds ``u · total`` — the searchsorted index for an exactly monotone
+prefix, and never a zero-mass (already selected) item when rounding makes
+the prefix dip by an ulp.
 
 Degenerate spectra: when the total residual mass collapses below
 ``MASS_EPS`` (numerically rank-deficient factors exhaust the column span
-early), the step marks the sample dead instead of re-picking the clamped
-index N-1 — remaining slots stay -1, mirroring the reference's early
-exit. This is the duplicate-items bugfix shared by both backends.
+early), the step marks the sample dead instead of picking — remaining
+slots stay -1, the loop exits.
 
-``interpret=True`` runs the same kernel as XLA on CPU/GPU (tests, and the
-honest CPU benchmark); the compiled path targets TPU where the ops.py
-wrapper picks MXU-aligned tiles.
+``interpret=True`` runs the same kernel as XLA on CPU (tests); on TPU the
+``kernels.ops`` wrapper always compiles it.
 """
 
 from __future__ import annotations
@@ -56,6 +63,16 @@ EPS = 1e-30
 #: steps have mass k_eff - t >= 1, so 1e-6 is many orders conservative.
 MASS_EPS = 1e-6
 
+SUBLANE, LANE = 8, 128
+_HIGHEST = jax.lax.Precision.HIGHEST
+#: Masked-min sentinel for "no position"; positions are exact in f32
+#: below 2**24, which ``ops.phase2_select`` enforces.
+_NO_POS = float(2 ** 24)
+
+
+def _round_up(x: int, m: int) -> int:
+    return ((x + m - 1) // m) * m
+
 
 def fold_trailing(Gs: Tuple[jax.Array, ...]) -> Tuple[jax.Array, ...]:
     """(G_1, ..., G_m) -> (G_1, G_r): elementwise-product fold of the
@@ -72,146 +89,189 @@ def fold_trailing(Gs: Tuple[jax.Array, ...]) -> Tuple[jax.Array, ...]:
 
 
 def canonical_pair(Gs: Tuple[jax.Array, ...]) -> Tuple[jax.Array, jax.Array]:
-    """Exactly two factors: fold the trailing ones, synthesize a ones()
-    second factor for m = 1. Shared by the kernel wrapper AND the jax
-    reference so both run bit-identical arithmetic (draw-for-draw picks)."""
+    """Exactly two factors: fold the trailing ones; for m = 1 synthesize a
+    ones() LEADING factor, so the N items lie along the 128-lane axis of
+    the norms grid. Shared by the kernel wrapper AND the jax reference so
+    both run bit-identical arithmetic (draw-for-draw picks)."""
     Gs = fold_trailing(Gs)
     if len(Gs) == 2:
         return Gs[0], Gs[1]
-    G1 = Gs[0]
-    ones = jnp.ones(G1.shape[:-2] + (1, G1.shape[-1]), G1.dtype)
-    return G1, ones
+    G = Gs[0]
+    return jnp.ones(G.shape[:-2] + (1, G.shape[-1]), G.dtype), G
 
 
-def _phase2_kernel(us_ref, keff_ref, g1_ref, gr_ref, picks_ref,
-                   norms_ref, b_ref, w_ref, flag_ref,
-                   *, k_max: int, bn1: int, n_tiles: int, Nr: int, N: int,
-                   merged: bool):
-    t = pl.program_id(1)
-    p = pl.program_id(2)
-    j = pl.program_id(3)
-    # single-tile layout: the downdate/select ordering that the two-phase
-    # grid enforces across tiles is trivially sequential inside one body,
-    # so both phases run in the same grid step (half the dispatches —
-    # the batch-1 latency case)
-    in_update = p == 0
-    in_select = in_update if merged else (p == 1)
+def padded_dims(N1: int, Nr: int, k: int) -> Tuple[int, int, int]:
+    """(N1p, Nrp, K): the (8, 128)-aligned shapes both engines run on."""
+    return _round_up(N1, SUBLANE), _round_up(Nr, LANE), _round_up(k, SUBLANE)
 
-    @pl.when((t == 0) & (p == 0) & (j == 0))
-    def _init():
-        picks_ref[...] = jnp.full((1, k_max), -1, jnp.int32)
-        b_ref[...] = jnp.zeros((k_max, k_max), jnp.float32)
-        flag_ref[0] = 1          # alive: residual mass not yet collapsed
-        flag_ref[1] = 0          # pick of the current/last live step
 
-    keff = keff_ref[0, 0]
-    g1 = g1_ref[0]               # (bn1, k) streamed tile
-    gr = gr_ref[0]               # (Nr, k) resident fold
-    alive = flag_ref[0] == 1
-    live = (t < keff) & alive
+def pad_pair(us: jax.Array, G1: jax.Array, Gr: jax.Array
+             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Zero-pad (us (..., k), G1 (..., N1, k), Gr (..., Nr, k)) to
+    ``padded_dims`` — works on unbatched and batched stacks alike."""
+    N1p, Nrp, K = padded_dims(G1.shape[-2], Gr.shape[-2], G1.shape[-1])
 
-    # -- phase 0: norms init (t == 0) / downdate off B[:, t-1] (t > 0) ----
-    @pl.when(in_update & (t == 0))
-    def _norms0():
-        norms_ref[pl.ds(j * bn1, bn1), :] = (g1 * g1) @ (gr * gr).T
+    def pad(x, rows):
+        widths = [(0, 0)] * (x.ndim - 2) + [(0, rows - x.shape[-2]),
+                                            (0, K - x.shape[-1])]
+        return jnp.pad(x.astype(jnp.float32), widths)
 
-    @pl.when(in_update & (t > 0) & live)
-    def _downdate():
-        q = b_ref[:, pl.ds(t - 1, 1)]            # (k, 1)
-        a = g1 * q.reshape(1, -1)
-        ct = a @ gr.T                            # (bn1, Nr)
-        tile = norms_ref[pl.ds(j * bn1, bn1), :]
-        norms_ref[pl.ds(j * bn1, bn1), :] = jnp.maximum(tile - ct * ct, 0.0)
-        i_prev = flag_ref[1]
-        i1 = i_prev // Nr
-        ir = i_prev - i1 * Nr
+    us = jnp.pad(us.astype(jnp.float32),
+                 [(0, 0)] * (us.ndim - 1) + [(0, K - us.shape[-1])])
+    return us, pad(G1, N1p), pad(Gr, Nrp)
 
-        @pl.when((i1 >= j * bn1) & (i1 < (j + 1) * bn1))
-        def _zero_pick():                        # .at[i].set(0.0)
-            norms_ref[pl.ds(i1, 1), pl.ds(ir, 1)] = jnp.zeros((1, 1),
-                                                              jnp.float32)
 
-    # -- phase 1: inverse-CDF select + CGS2 + pick ------------------------
-    @pl.when(in_select & (j == 0) & live)
-    def _select():
-        csum = jnp.cumsum(norms_ref[...].reshape(-1))
-        total = csum[-1]
-        # searchsorted(csum, r, side="right") == #(csum <= r) on the
-        # non-decreasing cumsum — identical index, vectorized form
-        r = us_ref[0, t] * total
-        i = jnp.sum((csum <= r).astype(jnp.int32))
-        flag_ref[1] = jnp.minimum(i, N - 1)
-        flag_ref[0] = jnp.where(total > MASS_EPS, 1, 0)
+def _dot_nt(a: jax.Array, b: jax.Array) -> jax.Array:
+    """a @ b.T, f32 accumulate at full precision."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
 
-    # re-read: a collapsed step must not pick (sequential ref semantics)
-    alive_now = flag_ref[0] == 1
-    live_now = (t < keff) & alive_now
-    i = flag_ref[1]
+
+def _dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _iota(shape, axis: int) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _inclusive_scan(x: jax.Array, axis: int, roll) -> jax.Array:
+    """Hillis-Steele inclusive prefix sum along ``axis`` of a 2-D array:
+    log2(n) rolled adds, the same f32 adds in the same order on every
+    backend."""
+    idx = _iota(x.shape, axis)
+    s = 1
+    while s < x.shape[axis]:
+        x = x + jnp.where(idx >= s, roll(x, s, axis), 0.0)
+        s *= 2
+    return x
+
+
+def prefix_mass(norms: jax.Array, roll=jnp.roll) -> jax.Array:
+    """Row-major inclusive prefix sum of the (N1p, Nrp) residual norms:
+    a lane scan within each row plus the exclusive sublane scan of the
+    row totals. ``roll`` is ``jnp.roll`` in XLA and ``pltpu.roll`` (the
+    same data movement) inside the kernel."""
+    row = _inclusive_scan(norms, 1, roll)
+    tot = jnp.broadcast_to(jnp.max(row, axis=1, keepdims=True), row.shape)
+    inc = _inclusive_scan(tot, 0, roll)
+    first = _iota(row.shape, 0) == 0
+    return row + jnp.where(first, 0.0, roll(inc, 1, 0))
+
+
+def select_step(t, u, g1, gr, norms, bt, *, N1: int, Nr: int,
+                roll=jnp.roll):
+    """One chain-rule step on padded operands — the arithmetic both
+    engines share.
+
+    t: () int32 step; u: () f32 uniform; g1 (N1p, K); gr (Nrp, K);
+    norms (N1p, Nrp) residual mass; bt (K, K) basis rows q_0..q_{t-1}.
+    Returns (alive, i, norms', bt'): alive is False when the residual
+    mass has collapsed (nothing may be picked), i the picked item.
+    """
+    csum = prefix_mass(norms, roll)
+    total = jnp.max(csum)
+    r = u * total
+    rows, cols = _iota(norms.shape, 0), _iota(norms.shape, 1)
+    pos = (rows * Nr + cols).astype(jnp.float32)
+    has = (rows < N1) & (cols < Nr) & (norms > 0.0)
+    first = jnp.min(jnp.where(has & (csum > r), pos, _NO_POS))
+    last = jnp.max(jnp.where(has, pos, -1.0))
+    i = jnp.maximum(jnp.where(first < _NO_POS, first, last),
+                    0.0).astype(jnp.int32)
     i1 = i // Nr
     ir = i - i1 * Nr
-
-    @pl.when(in_select & live_now & (i1 >= j * bn1) & (i1 < (j + 1) * bn1))
-    def _gather_row():
-        w_ref[...] = g1_ref[0, pl.ds(i1 - j * bn1, 1), :] * \
-            gr_ref[0, pl.ds(ir, 1), :]
-
-    @pl.when(in_select & (j == n_tiles - 1) & live_now)
-    def _orthogonalize():
-        w = w_ref[0, :]
-        B = b_ref[...]
-        q = w - B @ (B.T @ w)
-        q = q - B @ (B.T @ q)                    # CGS2: second pass
-        qn2 = jnp.sum(q * q)
-        q = jnp.where(qn2 > EPS,
-                      q / jnp.sqrt(jnp.maximum(qn2, EPS)), 0.0)
-        b_ref[:, pl.ds(t, 1)] = q.reshape(-1, 1)
-        picks_ref[0, t] = i
+    row1 = jnp.sum(jnp.where(_iota(g1.shape, 0) == i1, g1, 0.0), axis=0,
+                   keepdims=True)
+    rowr = jnp.sum(jnp.where(_iota(gr.shape, 0) == ir, gr, 0.0), axis=0,
+                   keepdims=True)
+    w = row1 * rowr                              # (1, K) row V[i]
+    q = w - _dot(_dot_nt(w, bt), bt)
+    q = q - _dot(_dot_nt(q, bt), bt)             # CGS2: second pass
+    qn2 = jnp.sum(q * q)
+    q = jnp.where(qn2 > EPS, q / jnp.sqrt(jnp.maximum(qn2, EPS)), 0.0)
+    ct = _dot_nt(g1 * q, gr)                     # (N1p, Nrp) = V q
+    picked = (rows == i1) & (cols == ir)
+    norms = jnp.where(picked, 0.0, jnp.maximum(norms - ct * ct, 0.0))
+    bt = jnp.where(_iota(bt.shape, 0) == t, q, bt)
+    return total > MASS_EPS, i, norms, bt
 
 
-@functools.partial(jax.jit, static_argnames=("block_n1", "interpret"))
+def init_norms(g1: jax.Array, gr: jax.Array) -> jax.Array:
+    """norms[n1, nr] = sum_c G1[n1, c]^2 Gr[nr, c]^2 — the diagonal of K."""
+    return _dot_nt(g1 * g1, gr * gr)
+
+
+def _phase2_kernel(keff_ref, us_ref, g1_ref, gr_ref, picks_ref,
+                   norms_ref, bt_ref, *, N1: int, Nr: int):
+    keff = keff_ref[pl.program_id(0)]
+    g1 = g1_ref[0]                               # (N1p, K)
+    gr = gr_ref[0]                               # (Nrp, K)
+    us = us_ref[0]                               # (1, K)
+    lanes = _iota(us.shape, 1)
+    norms_ref[...] = init_norms(g1, gr)
+    bt_ref[...] = jnp.zeros(bt_ref.shape, jnp.float32)
+    picks_ref[0] = jnp.full(us.shape, -1, jnp.int32)
+
+    def cond(carry):
+        t, alive = carry
+        return (t < keff) & (alive > 0)
+
+    def body(carry):
+        t, _ = carry
+        u = jnp.sum(jnp.where(lanes == t, us, 0.0))
+        alive, i, norms, bt = select_step(t, u, g1, gr, norms_ref[...],
+                                          bt_ref[...], N1=N1, Nr=Nr,
+                                          roll=pltpu.roll)
+
+        @pl.when(alive)
+        def _commit():
+            norms_ref[...] = norms
+            bt_ref[...] = bt
+            picks_ref[0] = jnp.where(lanes == t, i, picks_ref[0])
+
+        return t + 1, alive.astype(jnp.int32)
+
+    jax.lax.while_loop(cond, body, (jnp.int32(0), jnp.int32(1)))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def phase2_select_pallas(us: jax.Array, k_eff: jax.Array,
                          G1: jax.Array, Gr: jax.Array,
-                         block_n1: int = 0, interpret: bool = False
-                         ) -> jax.Array:
+                         interpret: bool = False) -> jax.Array:
     """Fused batched phase-2 selection off a canonical factor pair.
 
     us:    (B, k_max) per-step uniforms.
     k_eff: (B,) int32 — live step count per sample (<= k_max).
     G1:    (B, N1, k_max) leading factor columns.
     Gr:    (B, Nr, k_max) trailing-factor fold (``canonical_pair``).
-    block_n1: G1 rows streamed per tile (0 = whole factor, one tile).
     Returns (B, k_max) int32 picks, -1 in padded/dead slots.
     """
     B, k_max = us.shape
     N1, Nr = G1.shape[1], Gr.shape[1]
-    N = N1 * Nr
-    bn1 = N1 if block_n1 <= 0 else min(block_n1, N1)
-    n_tiles = -(-N1 // bn1)
-    N1p = n_tiles * bn1
-    if N1p != N1:           # zero rows: zero mass, never selected
-        G1 = jnp.pad(G1, ((0, 0), (0, N1p - N1), (0, 0)))
-    merged = n_tiles == 1   # single tile: both phases in one grid step
-    kern = functools.partial(_phase2_kernel, k_max=k_max, bn1=bn1,
-                             n_tiles=n_tiles, Nr=Nr, N=N, merged=merged)
-    return pl.pallas_call(
+    us, G1, Gr = pad_pair(us, G1, Gr)
+    N1p, Nrp, K = G1.shape[1], Gr.shape[1], G1.shape[2]
+    kern = functools.partial(_phase2_kernel, N1=N1, Nr=Nr)
+    picks = pl.pallas_call(
         kern,
-        grid=(B, k_max, 1 if merged else 2, n_tiles),
-        in_specs=[
-            pl.BlockSpec((1, k_max), lambda b, t, p, j: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b, t, p, j: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bn1, k_max), lambda b, t, p, j: (b, j, 0)),
-            pl.BlockSpec((1, Nr, k_max), lambda b, t, p, j: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, k_max), lambda b, t, p, j: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, k_max), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM((N1p, Nr), jnp.float32),      # residual norms
-            pltpu.VMEM((k_max, k_max), jnp.float32),  # Gram-Schmidt basis
-            pltpu.VMEM((1, k_max), jnp.float32),      # gathered row w
-            pltpu.SMEM((2,), jnp.int32),              # alive, pick
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, 1, K), lambda b, ke: (b, 0, 0)),
+                pl.BlockSpec((1, N1p, K), lambda b, ke: (b, 0, 0)),
+                pl.BlockSpec((1, Nrp, K), lambda b, ke: (b, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, K), lambda b, ke: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((N1p, Nrp), jnp.float32),   # residual norms
+                pltpu.VMEM((K, K), jnp.float32),       # basis rows
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, 1, K), jnp.int32),
         interpret=interpret,
-    )(us.astype(jnp.float32), k_eff.reshape(B, 1).astype(jnp.int32),
-      G1.astype(jnp.float32), Gr.astype(jnp.float32))
+    )(k_eff.astype(jnp.int32), us.reshape(B, 1, K), G1, Gr)
+    return picks[:, 0, :k_max]

@@ -27,8 +27,9 @@ def partial_trace_C_ref(theta4: jax.Array, L1: jax.Array) -> jax.Array:
     return jnp.einsum("iujv,ij->uv", theta4, L1).astype(jnp.float32)
 
 
-def greedy_map_update_ref(lcol, C, cj, dj, d):
-    e = (lcol - C @ cj) / jnp.sqrt(jnp.maximum(dj[0], 1e-12))
+def greedy_map_update_ref(lcol, Ct, cj, dj, d):
+    """e = (L[:, j] - C @ C[j]) / sqrt(d_j), with Ct = C^T (k, N)."""
+    e = (lcol - cj @ Ct) / jnp.sqrt(jnp.maximum(dj[0], 1e-12))
     return e.astype(jnp.float32), (d - e * e).astype(jnp.float32)
 
 

@@ -191,11 +191,14 @@ def compile_once(arch: str, shape_name: str, multi_pod: bool,
         "arch": arch, "shape": shape_name,
         "mesh": dict(mesh.shape), "chips": n_chips,
         "kind": shape.kind, "seq_len": shape.seq_len,
+        # what the compile targeted: benchmarks/roofline.py keys its peaks
+        # by this and refuses kinds it has no published numbers for
+        "device_kind": mesh.devices.flat[0].device_kind,
         "global_batch": shape.global_batch,
         "params": cfg.param_count(), "active_params": cfg.active_param_count(),
     }
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             params_s = jax.eval_shape(lm.init_params, jax.random.PRNGKey(0))  # repro: ignore[prng-literal-key] -- shape-only probe
             opt = AdamW()

@@ -72,8 +72,10 @@ def main():
                  "run log)")
 
     import jax
-    from .. import obs
+    from .. import compile_cache, obs
     from ..dpp import MAX_DENSE_N, random_kron, runtime, schedules
+
+    compile_cache.configure()
 
     if args.jsonl:
         obs.configure(obs.current_tracker(), jsonl=args.jsonl)

@@ -24,7 +24,10 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        # Auto axes: model code shards by GSPMD hints
+        # (``distributed.constraints``), not by sharding-in-types
+        return jax.make_mesh(shape, axes,
+                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} — "

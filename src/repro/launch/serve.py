@@ -51,10 +51,12 @@ def main():
     args = ap.parse_args()
 
     import jax
+    from .. import compile_cache
     from ..configs import get_config, smoke_config
     from ..models import LM
     from ..serve import ServeEngine
 
+    compile_cache.configure()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     lm = LM(cfg)
     params = lm.init_params(jax.random.PRNGKey(args.seed))

@@ -38,9 +38,8 @@ import jax.numpy as jnp
 from ..core.kron import split_indices_multi
 from ..kernels import ops as kernel_ops
 from ..kernels.ops import kron_eigvec_batch
-from ..kernels.phase2_select import EPS as _EPS
-from ..kernels.phase2_select import MASS_EPS as _MASS_EPS
-from ..kernels.phase2_select import canonical_pair
+from ..kernels.phase2_select import (canonical_pair, init_norms, pad_pair,
+                                     select_step)
 from .spectral import FactorSpectrum, log_product_spectrum
 
 
@@ -113,32 +112,6 @@ def assemble_eigvecs(spectrum_vecs: Tuple[jax.Array, ...],
     return V * valid[None, :].astype(V.dtype)
 
 
-def _colspace_matvec(Gs: Tuple[jax.Array, ...], q: jax.Array) -> jax.Array:
-    """ct[n] = sum_c q_c · prod_f Gs[f][n_f, c] — i.e. V @ q without
-    materializing V: fold the small factors and finish with one
-    (N/N_m, k) x (k, N_m) matmul, so per call only O(N) is written and
-    only O(sum N_f · k) is read.
-    """
-    A = Gs[0] * q[None, :]
-    for G in Gs[1:-1]:
-        A = (A[:, None, :] * G[None, :, :]).reshape(-1, q.shape[0])
-    if len(Gs) > 1:
-        return (A @ Gs[-1].T).reshape(-1)
-    return A.sum(axis=1)
-
-
-def _row_product(Gs: Tuple[jax.Array, ...], sizes: Tuple[int, ...],
-                 i: jax.Array) -> jax.Array:
-    """Row V[i] as the elementwise product of per-factor rows — O(m k)."""
-    w = None
-    rem = i
-    for G, s in zip(Gs[::-1], sizes[::-1]):
-        row = G[rem % s]
-        w = row if w is None else w * row
-        rem = rem // s
-    return w
-
-
 def phase2_select_reference(us: jax.Array, Gs: Tuple[jax.Array, ...],
                             sizes: Tuple[int, ...], k_eff: jax.Array
                             ) -> jax.Array:
@@ -147,70 +120,54 @@ def phase2_select_reference(us: jax.Array, Gs: Tuple[jax.Array, ...],
     per step in ``us``. Returns (k_max,) int32 picks, -1 in padded slots.
 
     This is the jax reference (and the CPU/GPU production path) that the
-    fused Pallas kernel must match draw-for-draw; both canonicalize the
-    factors to the (G1, Gr) pair so the arithmetic is bit-identical. For
-    m >= 3 that folds the trailing factors into one (N/N_1, k) block ONCE
-    per sample — the same O(N/N_1 · k) bytes the old per-step
-    ``_colspace_matvec`` intermediate materialized on every step, paid a
-    single time instead.
+    fused Pallas kernel must match draw-for-draw: both canonicalize the
+    factors to the (G1, Gr) pair, pad it to the same (8, 128)-aligned
+    shapes (``kernels.phase2_select.pad_pair``) and run the same step
+    function (``select_step``). For m >= 3 the trailing factors fold into
+    one (N/N_1, k) block ONCE per sample.
 
     Chain rule on the marginal kernel K = V V^T, run in the k-dimensional
     coefficient space: selecting item i conditions the remaining process
     on the span of row V[i], so we Gram-Schmidt the selected *rows* into
-    an orthonormal basis B (k_max x k_max, tiny) and downdate the
-    per-item residual variances norms -= (V q_t)^2. V is never built —
-    rows and the one matvec per step come off the factored columns
-    (``_row_product`` / ``_colspace_matvec``). Categorical draws are
-    inverse-CDF on the norms cumsum; selected items get exactly zero mass
-    so no chosen-mask is needed.
+    an orthonormal basis (one basis row per step, tiny) and downdate the
+    per-item residual variances norms -= (V q_t)^2, held as an
+    (N1, Nr) grid. V is never built. Categorical draws are inverse-CDF on
+    the row-major prefix mass; selected items get exactly zero mass and
+    are never drawn again.
 
     Degenerate spectra: numerically rank-deficient factors can exhaust
-    the selectable mass while t < k_eff (``csum[-1] <= MASS_EPS``); the
-    loop then exits early with the remaining slots at -1 — the old
-    behavior re-picked the clamped index N-1 every remaining step,
-    emitting duplicate items.
+    the selectable mass while t < k_eff (total <= MASS_EPS); the loop
+    then exits early with the remaining slots at -1.
 
     The loop is a ``while_loop`` bounded by the *data-dependent* k_eff
     (<= the static k_max): a typical draw has |J| well under the k_max
     tail bound, so under vmap the batch pays for its slowest lane rather
     than everyone running k_max masked steps.
     """
-    Gs = canonical_pair(Gs)
-    fsizes = tuple(int(G.shape[0]) for G in Gs)
-    k_max = Gs[0].shape[1]
-    N = fsizes[0] * fsizes[1]
-    norms0 = _colspace_matvec(tuple(G * G for G in Gs),
-                              jnp.ones((k_max,), Gs[0].dtype))
-    B0 = jnp.zeros((k_max, k_max), Gs[0].dtype)
-    picks0 = jnp.full((k_max,), -1, jnp.int32)
+    G1, Gr = canonical_pair(Gs)
+    N1, Nr = int(G1.shape[0]), int(Gr.shape[0])
+    k_max = us.shape[0]
+    us, g1, gr = pad_pair(us, G1, Gr)
+    K = g1.shape[1]
 
     def cond(state):
         t, alive = state[0], state[1]
         return (t < k_eff) & alive
 
     def body(state):
-        t, _, B, norms, picks = state
-        csum = jnp.cumsum(norms)
-        alive = csum[-1] > _MASS_EPS
-        i = jnp.searchsorted(csum, us[t] * csum[-1], side="right")
-        i = jnp.minimum(i, N - 1).astype(jnp.int32)
-        w = _row_product(Gs, fsizes, i)
-        q = w - B @ (B.T @ w)
-        q = q - B @ (B.T @ q)          # CGS2: second pass kills drift
-        qn2 = jnp.sum(q * q)           # == norms[i] up to roundoff
-        q = jnp.where(qn2 > _EPS,
-                      q / jnp.sqrt(jnp.maximum(qn2, _EPS)), 0.0)
-        ct = _colspace_matvec(Gs, q)
-        norms_new = jnp.maximum(norms - ct * ct, 0.0).at[i].set(0.0)
+        t, _, bt, norms, picks = state
+        alive, i, norms_new, bt_new = select_step(t, us[t], g1, gr, norms,
+                                                  bt, N1=N1, Nr=Nr)
         norms = jnp.where(alive, norms_new, norms)
-        B = jnp.where(alive, B.at[:, t].set(q), B)
+        bt = jnp.where(alive, bt_new, bt)
         picks = jnp.where(alive, picks.at[t].set(i), picks)
-        return t + 1, alive, B, norms, picks
+        return t + 1, alive, bt, norms, picks
 
     _, _, _, _, picks = jax.lax.while_loop(
         cond, body, (jnp.asarray(0, jnp.int32), jnp.asarray(True),
-                     B0, norms0, picks0))
-    return picks
+                     jnp.zeros((K, K), jnp.float32), init_norms(g1, gr),
+                     jnp.full((K,), -1, jnp.int32)))
+    return picks[:k_max]
 
 
 def phase2_select(key: jax.Array, Gs: Tuple[jax.Array, ...],
@@ -339,7 +296,4 @@ def picks_to_lists(picks):
 def compile_cache_size() -> int:
     """Number of compiled (k_max, batch) specializations — test hook for
     the 'one compile per shape' contract."""
-    try:
-        return _sample_batched._cache_size()
-    except AttributeError:   # older jax: no introspection, don't fail tests
-        return -1
+    return _sample_batched._cache_size()

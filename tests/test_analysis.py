@@ -103,7 +103,7 @@ def test_shardmap_sort(tmp_path):
                     pick = jax.random.choice(key, x.shape[0], (3,),
                                              replace=False)
                     return jax.numpy.sort(x[pick])
-                return shard_map_compat(body, mesh, specs, specs)
+                return shard_map_unchecked(body, mesh, specs, specs)
             """,
         "src/repro/core/ok.py": """\
             import jax
@@ -114,8 +114,8 @@ def test_shardmap_sort(tmp_path):
             def make(mesh, specs, fn):
                 def body(x):
                     return x - x.mean()
-                shard_map_compat(body, mesh, specs, specs)
-                return shard_map_compat(fn, mesh, specs, specs)  # opaque: skip
+                shard_map_unchecked(body, mesh, specs, specs)
+                return shard_map_unchecked(fn, mesh, specs, specs)  # opaque: skip
             """,
     })
     found = [(f.path, f.line) for f in run_rule(tmp_path, "shardmap-sort")]

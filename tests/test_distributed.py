@@ -18,7 +18,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _run_subprocess(code: str) -> str:
-    env = dict(os.environ,
+    # the child simulates devices on the CPU by design; pinned there, it
+    # never needs the chip that this process may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=SRC)
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -66,7 +68,8 @@ def test_sharded_train_step_runs_and_matches():
         from repro.optim import AdamW, OptState
         from repro.train.steps import make_train_step
         from repro.distributed.sharding import ShardingPolicy
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = smoke_config("qwen2-0.5b")
         lm = LM(cfg)
         params = lm.init_params(jax.random.PRNGKey(0))
@@ -83,7 +86,7 @@ def test_sharded_train_step_runs_and_matches():
                        m=policy.params_shardings(jax.eval_shape(lambda: ost.m)),
                        v=policy.params_shardings(jax.eval_shape(lambda: ost.v)))
         bs = policy.batch_shardings(jax.eval_shape(lambda: batch))
-        with mesh:
+        with jax.set_mesh(mesh):
             jstep = jax.jit(step, in_shardings=(ps, os_, bs))
             _, _, m_dist = jstep(jax.device_put(params, ps),
                                  jax.device_put(ost, os_),

@@ -210,7 +210,7 @@ def test_cached_theta_still_ascends(data, init):
 
 def test_distributed_fit_matches_local():
     src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",   # never the chip
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=src)
     out = subprocess.run([sys.executable, "-c", textwrap.dedent("""

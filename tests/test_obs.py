@@ -465,3 +465,18 @@ def test_committed_reports_are_gate_compatible():
         assert all(v > 0 for v, _ in metrics.values()), bench
         # a committed report always agrees with itself
         assert compare_reports(bench, report, report) == []
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from a table keyed by device_kind; a chip the table does
+    not know is an error, never another chip's numbers."""
+    from benchmarks.roofline import analyze, peaks
+    assert peaks("TPU v5 lite")["flops"] == 197e12
+    rec = {"arch": "a", "shape": "s", "chips": 256, "kind": "train",
+           "params": 1, "global_batch": 1, "seq_len": 1,
+           "flops_per_device": 1e12, "bytes_accessed_per_device": 1e9}
+    assert analyze({**rec, "device_kind": "TPU v5 lite"})["dominant"] \
+        == "compute"
+    for kind in ("cpu", None):
+        with pytest.raises(ValueError, match="no published peaks"):
+            analyze({**rec, "device_kind": kind})

@@ -57,20 +57,21 @@ def test_fused_matches_reference_draw_for_draw(sizes, seed):
 
 
 @pallas
-@pytest.mark.parametrize("block_n1", [16, 8, 5, 3])
-def test_fused_matches_reference_tiled_and_padded(block_n1):
-    """Streaming G1 in tiles (including non-divisors, which zero-pad the
-    factor) must not change a single pick."""
-    m = random_krondpp(jax.random.PRNGKey(7), (16, 4))
+@pytest.mark.parametrize("sizes", [(16, 4), (13, 5), (8, 128), (9, 130)])
+def test_fused_matches_reference_tiled_and_padded(sizes):
+    """Padding the factor pair to the (8, 128) tiling — aligned, ragged,
+    and more than one lane tile of trailing items — must not change a
+    single pick."""
+    m = random_krondpp(jax.random.PRNGKey(7), sizes)
     spec = SpectralCache().spectrum(m)
     lams, vecs = tuple(spec.lams), tuple(spec.vecs)
     keys = jax.random.split(jax.random.PRNGKey(0), 8)
     us, Gs, k_eff, _ = jax.vmap(
         lambda k: _phase1_one(k, lams, vecs, 8))(keys)
-    p_ref = ops.phase2_select(us, Gs, (16, 4), k_eff, backend="reference")
-    p_pal = ops.phase2_select(us, Gs, (16, 4), k_eff, backend="pallas",
-                              block_n1=block_n1)
+    p_ref = ops.phase2_select(us, Gs, sizes, k_eff, backend="reference")
+    p_pal = ops.phase2_select(us, Gs, sizes, k_eff, backend="pallas")
     np.testing.assert_array_equal(np.asarray(p_pal), np.asarray(p_ref))
+    _assert_rows_distinct(p_pal)
 
 
 @pallas

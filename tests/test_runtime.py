@@ -294,7 +294,7 @@ def test_mesh_matches_local_in_process():
 def test_mesh_matches_local_subprocess():
     """Tier-1 coverage of the 8-device equivalence suite: rerun this module
     under forced host devices (the main process must keep one device)."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",   # never the chip
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=SRC + os.pathsep + str(ROOT / "tests"))
     out = subprocess.run(

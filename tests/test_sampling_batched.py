@@ -143,9 +143,9 @@ def test_huge_spectrum_no_float32_overflow():
 def test_factored_columns_match_materialized_eigvecs():
     """phase 2 runs on factored columns; they must reproduce the
     materialized Kronecker eigenvectors (kron_eigvec_batch identity)."""
-    from repro.sampling.batched import (_colspace_matvec, _row_product,
-                                        assemble_eigvecs,
-                                        gather_factor_columns)
+    from repro.kernels.phase2_select import (_dot_nt, canonical_pair,
+                                             init_norms, pad_pair)
+    from repro.sampling.batched import assemble_eigvecs, gather_factor_columns
     m = random_krondpp(jax.random.PRNGKey(8), (3, 4))
     spec = SpectralCache().spectrum(m)
     sel = jnp.asarray([0, 5, 11, 7], jnp.int32)
@@ -154,12 +154,19 @@ def test_factored_columns_match_materialized_eigvecs():
     V = np.asarray(assemble_eigvecs(spec.vecs, sizes, sel, valid))
     Gs = gather_factor_columns(spec.vecs, sizes, sel, valid)
     q = jnp.asarray([0.3, -1.2, 0.5, 2.0], jnp.float32)
-    np.testing.assert_allclose(np.asarray(_colspace_matvec(Gs, q)), V @ np.asarray(q),
-                               rtol=1e-5, atol=1e-6)
+    qp, g1, gr = pad_pair(q, *canonical_pair(Gs))
+    # the phase-2 step's (N1, Nr) grid of V q and of the residual norms
+    np.testing.assert_allclose(
+        np.asarray(_dot_nt(g1 * qp, gr))[:3, :4].reshape(-1),
+        V @ np.asarray(q), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(init_norms(g1, gr))[:3, :4].reshape(-1), (V * V).sum(1),
+        rtol=1e-5, atol=1e-6)
     for i in (0, 7, 11):
         np.testing.assert_allclose(
-            np.asarray(_row_product(Gs, sizes, jnp.asarray(i))), V[i],
-            rtol=1e-5, atol=1e-6)
+            np.asarray(g1[i // 4] * gr[i % 4])[:4], V[i], rtol=1e-5,
+            atol=1e-6)
+    assert not np.asarray(g1[3:]).any() and not np.asarray(gr[4:]).any()
 
 
 # ---------------------------------------------------------------------------

@@ -112,3 +112,22 @@ def test_straggler_hook_fires():
                  straggler_hook=lambda s, dt: fired.append((s, dt)))
     tr.fit(params, opt.init(params), iter(TokenPipeline(corpus, 4)))
     assert fired, "straggler deadline hook did not fire"
+
+
+def test_compile_cache_location(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache goes
+    to the checkout's fixed, git-ignored .jax_cache."""
+    import pathlib
+    from repro import compile_cache
+    root = pathlib.Path(__file__).resolve().parents[1]
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.configure() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.configure() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
