@@ -2,18 +2,18 @@
 
 The batched sampler amortizes beautifully at batch >= 32, but batch-1
 latency — the serve KV-compaction path — is bounded by the phase-2
-``lax.while_loop`` of O(k_eff) small steps (cumsum -> searchsorted ->
-row product -> CGS2 -> colspace matvec -> norms downdate). The fused
-kernel (``kernels.phase2_select``) runs the whole loop inside one
-``pallas_call`` with the Gram-Schmidt basis and residual norms resident
-in VMEM.
+``lax.while_loop`` of O(k_eff) small steps (row totals and their prefix
+-> scan of the picked row -> row product -> CGS2 -> colspace matvec ->
+norms downdate, ``kernels.phase2_select.select_step``). The fused kernel
+runs the same steps inside one ``pallas_call`` with the Gram-Schmidt
+basis and residual norms resident in VMEM.
 
 On CPU the fused path necessarily runs in *interpret mode* — the Pallas
-grid is emulated as XLA over all k_max x 2 x n_tiles steps, where the
-while_loop stops at the data-dependent k_eff — so the CPU numbers below
-are an honest lower bound for the kernel, not the TPU story (there the
-while_loop pays its per-step HBM re-reads and the kernel does not).
-Draw-for-draw equality of the two engines is asserted before timing.
+grid (one sample per step, each running its own while_loop) is emulated
+as XLA, with the in-kernel vreg rolls and iota-mask gathers where the
+reference slices and indexes — so the CPU numbers below say nothing of
+the compiled kernel on a TPU. Draw-for-draw equality of the two engines
+is asserted before timing.
 """
 
 from __future__ import annotations

@@ -101,10 +101,11 @@ def kron_eigvec_batch(P1: jax.Array, P2: jax.Array, i: jax.Array,
 def _phase2_fits(N1: int, Nr: int, k: int) -> bool:
     """Whether the fused kernel's VMEM set fits the budget: the padded
     factor pair (double-buffered), the basis, and the (N1p, Nrp) norms
-    grid plus the step's few same-shape temporaries."""
+    grid plus the step's few same-shape temporaries. ``padded_dims``
+    raises, for either engine, on a grid its picks cannot index."""
     N1p, Nrp, K = padded_dims(N1, Nr, k)
     need = (4 * (N1p + Nrp) * K + K * K + 6 * N1p * Nrp) * 4
-    return need <= _VMEM_BUDGET and N1 * Nr < 2 ** 24
+    return need <= _VMEM_BUDGET
 
 
 def phase2_select(us, Gs, sizes, k_eff, backend=None):

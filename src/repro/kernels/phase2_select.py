@@ -20,20 +20,33 @@ factors     canonicalized to exactly two: the leading block G1 (N1, k) and
 layout      ``pad_pair`` pads N1 and k to the 8-row sublane tiling and Nr
             to the 128-lane tiling with zeros: padded items
             carry zero mass and are never selected, padded columns add
-            exact zeros to every product.
+            exact zeros to every product. A one-row leading factor
+            (m = 1) stays one row: its block equals the full array.
 
 Both engines run one step function, ``select_step``, on the same padded
 arrays, so on the CPU their picks agree draw for draw (the property tests
-assert equality). The step is written in the operations Mosaic lowers:
-the row-major prefix mass is a Hillis-Steele scan of ``pltpu.roll`` shifts
-(adds in a fixed order — no cumsum), the inverse-CDF index is a masked
-min over an index grid, rows are gathered and written with iota masks
-(no dynamic single-lane stores), and every dot runs at HIGHEST precision.
+assert equality). The step is written in the operations Mosaic lowers —
+no cumsum, no dynamic single-lane stores, every dot at HIGHEST precision —
+and draws its item in two levels, so each float32 index stays exact
+(below 2**24) for any N the (N1p, Nrp) grid can hold:
+
+row         per-row mass by a fixed fold (lane tiles left to right, then
+            halving the 128 lanes), a Hillis-Steele scan of the row
+            totals, and the first row whose inclusive prefix exceeds
+            ``u · total``;
+column      a Hillis-Steele scan of that one row, offset by the mass of
+            the rows before it, and its first column past the target.
+
+The two engines do the same float32 adds in the same order and differ
+only in how they move data: the kernel shifts whole vregs with
+``pltpu.roll`` and gathers rows with iota masks; the XLA engine
+(``roll=None``) slices and indexes, so a step costs it about two passes
+over the grid (the fold and the norms downdate) instead of a scan of it.
 
 Inverse CDF: the pick is the first positive-mass item whose prefix mass
-exceeds ``u · total`` — the searchsorted index for an exactly monotone
-prefix, and never a zero-mass (already selected) item when rounding makes
-the prefix dip by an ulp.
+exceeds ``u · total``; where rounding leaves no such item in the chosen
+row (or no such row), the last positive-mass one — never a zero-mass
+(already selected) item.
 
 Degenerate spectra: when the total residual mass collapses below
 ``MASS_EPS`` (numerically rank-deficient factors exhaust the column span
@@ -65,9 +78,11 @@ MASS_EPS = 1e-6
 
 SUBLANE, LANE = 8, 128
 _HIGHEST = jax.lax.Precision.HIGHEST
-#: Masked-min sentinel for "no position"; positions are exact in f32
-#: below 2**24, which ``ops.phase2_select`` enforces.
-_NO_POS = float(2 ** 24)
+#: Row and column indices are reduced as float32, exact up to 2**24 —
+#: ``padded_dims`` refuses a grid axis longer than this. It doubles as
+#: the masked-min "no index" sentinel.
+MAX_AXIS = 2 ** 24
+_NO_POS = float(MAX_AXIS)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -101,8 +116,19 @@ def canonical_pair(Gs: Tuple[jax.Array, ...]) -> Tuple[jax.Array, jax.Array]:
 
 
 def padded_dims(N1: int, Nr: int, k: int) -> Tuple[int, int, int]:
-    """(N1p, Nrp, K): the (8, 128)-aligned shapes both engines run on."""
-    return _round_up(N1, SUBLANE), _round_up(Nr, LANE), _round_up(k, SUBLANE)
+    """(N1p, Nrp, K): the (8, 128)-aligned shapes both engines run on
+    (a one-row leading factor stays one row). Raises ValueError where a
+    pick could not be indexed exactly: a grid axis above ``MAX_AXIS`` or
+    N1 * Nr items beyond int32."""
+    N1p = N1 if N1 == 1 else _round_up(N1, SUBLANE)
+    Nrp = _round_up(Nr, LANE)
+    if max(N1p, Nrp) > MAX_AXIS or N1 * Nr >= 2 ** 31:
+        raise ValueError(
+            f"phase-2 selection indexes the (N1, Nr) = ({N1}, {Nr}) item "
+            f"grid with float32 row/column indices (exact up to "
+            f"{MAX_AXIS}) and int32 items; this factorization is "
+            f"outside that range")
+    return N1p, Nrp, _round_up(k, SUBLANE)
 
 
 def pad_pair(us: jax.Array, G1: jax.Array, Gr: jax.Array
@@ -138,66 +164,159 @@ def _iota(shape, axis: int) -> jax.Array:
     return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
 
 
-def _inclusive_scan(x: jax.Array, axis: int, roll) -> jax.Array:
+def _shift(x: jax.Array, s: int, axis: int, roll=None) -> jax.Array:
+    """``x`` moved ``s`` places toward higher indices along ``axis``,
+    zero-filled: a padded slice in XLA, a masked ``roll`` in-kernel."""
+    if roll is not None:
+        return jnp.where(_iota(x.shape, axis) >= s, roll(x, s, axis), 0.0)
+    n = x.shape[axis]
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (s, 0)
+    return jnp.pad(jax.lax.slice_in_dim(x, 0, n - s, axis=axis), pad)
+
+
+def _inclusive_scan(x: jax.Array, axis: int, roll=None) -> jax.Array:
     """Hillis-Steele inclusive prefix sum along ``axis`` of a 2-D array:
-    log2(n) rolled adds, the same f32 adds in the same order on every
+    log2(n) shifted adds, the same f32 adds in the same order on every
     backend."""
-    idx = _iota(x.shape, axis)
     s = 1
     while s < x.shape[axis]:
-        x = x + jnp.where(idx >= s, roll(x, s, axis), 0.0)
+        x = x + _shift(x, s, axis, roll)
         s *= 2
     return x
 
 
-def prefix_mass(norms: jax.Array, roll=jnp.roll) -> jax.Array:
-    """Row-major inclusive prefix sum of the (N1p, Nrp) residual norms:
-    a lane scan within each row plus the exclusive sublane scan of the
-    row totals. ``roll`` is ``jnp.roll`` in XLA and ``pltpu.roll`` (the
-    same data movement) inside the kernel."""
-    row = _inclusive_scan(norms, 1, roll)
-    tot = jnp.broadcast_to(jnp.max(row, axis=1, keepdims=True), row.shape)
-    inc = _inclusive_scan(tot, 0, roll)
-    first = _iota(row.shape, 0) == 0
-    return row + jnp.where(first, 0.0, roll(inc, 1, 0))
+def _fold_tiles(norms: jax.Array, roll=None) -> jax.Array:
+    """(rows, T·128) -> (rows, 128): the 128-lane tiles folded in halves
+    over the next power of two >= T, tile j + h onto tile j (a tile past
+    the end counts as zeros). In XLA one padded slice-add per level;
+    in-kernel a list of aligned tiles. The same adds either way."""
+    T = norms.shape[1] // LANE
+    h = 1 << max(T - 1, 0).bit_length()
+    if roll is None:
+        x = norms
+        while h > 1:
+            h //= 2
+            n = x.shape[1] // LANE
+            lo, hi = x[:, :min(h, n) * LANE], x[:, h * LANE:]
+            x = lo + jnp.pad(hi, ((0, 0), (0, lo.shape[1] - hi.shape[1])))
+        return x
+    tiles = [norms[:, c * LANE:(c + 1) * LANE] for c in range(T)]
+    while h > 1:
+        h //= 2
+        tiles = [t + tiles[j + h] if j + h < len(tiles) else t
+                 for j, t in enumerate(tiles[:h])]
+    return tiles[0]
 
 
-def select_step(t, u, g1, gr, norms, bt, *, N1: int, Nr: int,
-                roll=jnp.roll):
+def row_totals(norms: jax.Array, roll=None) -> jax.Array:
+    """Per-row mass of a (rows, width) grid, (rows, 1): the lane tiles
+    folded (``_fold_tiles``), then the lanes folded in halves, lane j + s
+    onto lane j. ``roll=None`` slices; in-kernel the fold rolls whole
+    vregs (lane j meets lane j + s either way, so the sums are
+    bit-identical) and lane 0 is read out with an exact masked max."""
+    x = _fold_tiles(norms, roll) if norms.shape[1] > LANE else norms
+    s = x.shape[1] // 2
+    while s >= 1:
+        x = (x[:, :s] + x[:, s:2 * s] if roll is None
+             else x + roll(x, LANE - s, 1))
+        s //= 2
+    if roll is None:
+        return x
+    return jnp.max(jnp.where(_iota(x.shape, 1) == 0, x, -jnp.inf), axis=1,
+                   keepdims=True)
+
+
+def _row_mass(norms: jax.Array, N1: int, Nr: int, roll=None):
+    """(row totals, inclusive row prefix, exclusive row prefix, total).
+
+    In-kernel on the whole padded grid, the prefix broadcast across 128
+    lanes. The XLA engine drops what only adds exact zeros: rows past N1
+    (zero mass; a prefix entry depends only on the rows above it) and,
+    on a one-tile grid, the lanes past the next power of two >= Nr (fold
+    steps that wide add a zero to every lane they keep)."""
+    if roll is None:
+        width = norms.shape[1]
+        if width == LANE:
+            width = min(LANE, 1 << max(Nr - 1, 0).bit_length())
+        tot = rows = row_totals(norms[:N1, :width])
+    else:
+        tot = row_totals(norms, roll)
+        rows = jnp.broadcast_to(tot, (tot.shape[0], LANE))
+    if rows.shape[0] == 1:
+        inc, exc = rows, jnp.zeros_like(rows)
+    else:
+        inc = _inclusive_scan(rows, 0, roll)
+        exc = _shift(inc, 1, 0, roll)
+    real_row = _iota(inc.shape, 0) < N1
+    return tot, inc, exc, jnp.max(jnp.where(real_row, inc, 0.0))
+
+
+def prefix_mass(norms: jax.Array, N1: int, Nr: int):
+    """(total, csum): the total mass and the (N1, Nrp) row-major prefix
+    mass that ``select_step`` inverts (XLA; for diagnostics — a step
+    scans only the row it picks)."""
+    _, _, exc, total = _row_mass(norms, N1, Nr)
+    return total, exc + _inclusive_scan(norms[:N1], 1)
+
+
+def _first_or_last(hit, has, idx):
+    """Lowest ``idx`` where ``hit``; if none, highest where ``has``; else
+    0 — exact float32 min/max reductions."""
+    first = jnp.min(jnp.where(hit, idx, _NO_POS))
+    last = jnp.max(jnp.where(has, idx, -1.0))
+    return jnp.maximum(jnp.where(first < _NO_POS, first, last),
+                       0.0).astype(jnp.int32)
+
+
+def _row(x: jax.Array, i: jax.Array, roll) -> jax.Array:
+    """Row ``i`` of a 2-D array, (1, n): indexed in XLA; in-kernel an
+    iota-masked sum (one nonzero term per column, so exact)."""
+    if roll is None:
+        return jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=True)
+    return jnp.sum(jnp.where(_iota(x.shape, 0) == i, x, 0.0), axis=0,
+                   keepdims=True)
+
+
+def select_step(t, u, g1, gr, norms, bt, *, N1: int, Nr: int, roll=None):
     """One chain-rule step on padded operands — the arithmetic both
-    engines share.
+    engines share (``roll=None``: XLA; ``pltpu.roll``: in-kernel).
 
     t: () int32 step; u: () f32 uniform; g1 (N1p, K); gr (Nrp, K);
     norms (N1p, Nrp) residual mass; bt (K, K) basis rows q_0..q_{t-1}.
     Returns (alive, i, norms', bt'): alive is False when the residual
     mass has collapsed (nothing may be picked), i the picked item.
     """
-    csum = prefix_mass(norms, roll)
-    total = jnp.max(csum)
+    tot, inc, exc, total = _row_mass(norms, N1, Nr, roll)
     r = u * total
-    rows, cols = _iota(norms.shape, 0), _iota(norms.shape, 1)
-    pos = (rows * Nr + cols).astype(jnp.float32)
-    has = (rows < N1) & (cols < Nr) & (norms > 0.0)
-    first = jnp.min(jnp.where(has & (csum > r), pos, _NO_POS))
-    last = jnp.max(jnp.where(has, pos, -1.0))
-    i = jnp.maximum(jnp.where(first < _NO_POS, first, last),
-                    0.0).astype(jnp.int32)
-    i1 = i // Nr
-    ir = i - i1 * Nr
-    row1 = jnp.sum(jnp.where(_iota(g1.shape, 0) == i1, g1, 0.0), axis=0,
-                   keepdims=True)
-    rowr = jnp.sum(jnp.where(_iota(gr.shape, 0) == ir, gr, 0.0), axis=0,
-                   keepdims=True)
-    w = row1 * rowr                              # (1, K) row V[i]
+    rowf = _iota(inc.shape, 0).astype(jnp.float32)
+    real = (rowf < N1) & (tot > 0.0)
+    i1 = _first_or_last(real & (inc > r), real, rowf)
+    if roll is None:
+        nrow = jax.lax.dynamic_slice(norms, (i1, 0), (1, Nr))
+    else:
+        nrow = _row(norms, i1, roll)                         # (1, Nrp)
+    base = jnp.max(jnp.where(rowf == i1.astype(jnp.float32), exc, -1.0))
+    csum = base + _inclusive_scan(nrow, 1, roll)
+    colf = _iota(nrow.shape, 1).astype(jnp.float32)
+    has = (colf < Nr) & (nrow > 0.0)
+    ir = _first_or_last(has & (csum > r), has, colf)
+    w = _row(g1, i1, roll) * _row(gr, ir, roll)  # (1, K) row V[i]
     q = w - _dot(_dot_nt(w, bt), bt)
     q = q - _dot(_dot_nt(q, bt), bt)             # CGS2: second pass
     qn2 = jnp.sum(q * q)
     q = jnp.where(qn2 > EPS, q / jnp.sqrt(jnp.maximum(qn2, EPS)), 0.0)
     ct = _dot_nt(g1 * q, gr)                     # (N1p, Nrp) = V q
-    picked = (rows == i1) & (cols == ir)
-    norms = jnp.where(picked, 0.0, jnp.maximum(norms - ct * ct, 0.0))
-    bt = jnp.where(_iota(bt.shape, 0) == t, q, bt)
-    return total > MASS_EPS, i, norms, bt
+    norms = jnp.maximum(norms - ct * ct, 0.0)
+    if roll is None:
+        norms = jax.lax.dynamic_update_slice(
+            norms, jnp.zeros((1, 1), norms.dtype), (i1, ir))
+        bt = jax.lax.dynamic_update_slice(bt, q, (t, 0))
+    else:
+        picked = (_iota(norms.shape, 0) == i1) & (_iota(norms.shape, 1) == ir)
+        norms = jnp.where(picked, 0.0, norms)
+        bt = jnp.where(_iota(bt.shape, 0) == t, q, bt)
+    return total > MASS_EPS, i1 * Nr + ir, norms, bt
 
 
 def init_norms(g1: jax.Array, gr: jax.Array) -> jax.Array:

@@ -3,8 +3,10 @@
 The exact-DPP pipeline from ``sampling.batched`` transplanted to the
 rank-r dual representation: phase 1 draws eigen-indices over the r dual
 eigenvalues (Bernoulli for the DPP, the shared ESP recursion for the
-k-DPP), phase 2 runs the same projection-DPP Gram–Schmidt chain rule —
-bit-compatible arithmetic with ``phase2_select_reference`` — except the
+k-DPP), phase 2 runs the same projection-DPP Gram–Schmidt chain rule as
+``phase2_select_reference`` — with its own inverse CDF (a flat ``cumsum``
+and ``searchsorted``, not the Kron engines' two-level row/column search,
+so the two do not agree draw for draw) — and the
 orthonormal basis lives in r-dimensional *coefficient* space and rows of
 the implicit eigenvector matrix U = φ·E are projected through φ on
 demand. Per selection step that is one O(r·k) row product and one O(Nr)
@@ -45,8 +47,9 @@ def _phase2_dual_one(us: jax.Array, phi: jax.Array, Gamma: jax.Array,
     Gamma (r, k_max) holds the selected eigenvectors' coefficient
     columns (invalid slots zeroed), so the implicit row i of the
     selected eigenvector matrix is U[i] = Γᵀφ_i. Same chain-rule loop,
-    CGS2 re-orthogonalization, inverse-CDF draw, mass-exhaustion early
-    exit and -1 padding as ``phase2_select_reference``.
+    CGS2 re-orthogonalization, mass-exhaustion early exit and -1 padding
+    as ``phase2_select_reference``; the inverse-CDF draw is a flat
+    ``cumsum`` + ``searchsorted`` over the N items.
     """
     k_max = Gamma.shape[1]
     N = phi.shape[0]

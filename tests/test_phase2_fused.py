@@ -57,11 +57,12 @@ def test_fused_matches_reference_draw_for_draw(sizes, seed):
 
 
 @pallas
-@pytest.mark.parametrize("sizes", [(16, 4), (13, 5), (8, 128), (9, 130)])
+@pytest.mark.parametrize("sizes", [(16, 4), (13, 5), (8, 128), (9, 130),
+                                   (3, 600)])
 def test_fused_matches_reference_tiled_and_padded(sizes):
     """Padding the factor pair to the (8, 128) tiling — aligned, ragged,
-    and more than one lane tile of trailing items — must not change a
-    single pick."""
+    and more than one lane tile of trailing items (five tiles: a ragged
+    tile fold) — must not change a single pick."""
     m = random_krondpp(jax.random.PRNGKey(7), sizes)
     spec = SpectralCache().spectrum(m)
     lams, vecs = tuple(spec.lams), tuple(spec.vecs)
@@ -97,6 +98,41 @@ def test_fused_unbatched_entry_matches_batched_row():
     ref = ops.phase2_select(us, Gs, (4, 3), k_eff, backend="reference")
     assert one.shape == (6,)
     np.testing.assert_array_equal(np.asarray(one), np.asarray(ref))
+
+
+# ---------------------------------------------------------------------------
+# item indexing: exact beyond 2**24 items, refused where it cannot be
+# ---------------------------------------------------------------------------
+
+def test_reference_picks_exact_beyond_2_pow_24_items():
+    """N = 8 x (2**21 + 128) > 2**24 items: a float32 item position
+    rounds odd indices here. Two one-hot columns put all the mass on two
+    odd items above 2**24; the projection DPP must pick exactly both."""
+    N1, Nr = 8, 2 ** 21 + 128
+    cols = (Nr - 1, 2 ** 24 + 1 - 7 * Nr)        # both items in row 7
+    G1 = jnp.zeros((N1, 2), jnp.float32).at[7, :].set(1.0)
+    Gr = jnp.zeros((Nr, 2), jnp.float32).at[cols, (0, 1)].set(1.0)
+    want = sorted(7 * Nr + c for c in cols)
+    assert want[0] > 2 ** 24 and all(i % 2 for i in want)
+    for us in ([0.3, 0.7], [0.9, 0.1]):
+        picks = ops.phase2_select(jnp.asarray(us), (G1, Gr), (N1, Nr),
+                                  jnp.asarray(2, jnp.int32),
+                                  backend="reference")
+        assert sorted(np.asarray(picks).tolist()) == want
+
+
+@pytest.mark.parametrize("backend", [None, "reference", "pallas"])
+def test_phase2_select_refuses_unindexable_grid(backend):
+    """A trailing fold longer than 2**24 items cannot be indexed exactly
+    in float32: every engine raises instead of returning a neighbour."""
+    k = 2
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32)
+              for s in ((k,), (2, k), (2 ** 24 + 1, k))]
+    with pytest.raises(ValueError, match="outside that range"):
+        jax.eval_shape(
+            lambda us, g1, g2: ops.phase2_select(
+                us, (g1, g2), (2, 2 ** 24 + 1), jnp.asarray(k, jnp.int32),
+                backend=backend), *shapes)
 
 
 # ---------------------------------------------------------------------------

@@ -71,6 +71,17 @@ def test_phase2_select_compiles(compile_tpu, batch):
         ((batch,), I32))
 
 
+def test_phase2_select_dense_compiles(compile_tpu):
+    """One factor (a ``Dense`` model): the leading block is a single
+    row, compiled unpadded; its 600 items span five lane tiles."""
+    model = dpp.random_kron(jax.random.PRNGKey(0), (600,)).rescale(10.0)
+    k = model.spectrum().suggested_k_max()
+    compile_tpu(
+        lambda us, g, ke: ops.phase2_select(us, (g,), (600,), ke,
+                                            backend="pallas"),
+        ((64, k), F32), ((64, 600, k), F32), ((64,), I32))
+
+
 def test_greedy_map_compiles(compile_tpu):
     """``model.map`` / KV compaction: a (64, 64) Kron kernel, N = 4096."""
     compile_tpu(lambda L: ops.greedy_map_kdpp(L, 10), ((4096, 4096), F32))
