@@ -4,9 +4,8 @@ Prints ``name,us_per_call,derived`` CSV lines. Observability is wired
 through ``repro.obs``: every benchmark's metrics flow through the
 configured tracker (``common.json_report`` emits a ``benchmark.report``
 event per result), ``--jsonl`` captures the whole run as an append-only
-run log, and ``--profile`` wraps each benchmark in a ``jax.profiler``
-trace (one TensorBoard-loadable subdirectory per benchmark; see the
-README "Observability" section for reading them).
+run log, and ``--trace`` exports it as one Chrome trace-event file per
+benchmark (see the README "Observability" section).
 
 A benchmark that raises no longer lets the process end green: the
 harness keeps running the remaining benchmarks (so one broken module
@@ -17,7 +16,6 @@ every failure.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import os
 import sys
 import time
@@ -45,18 +43,6 @@ def _short(mod) -> str:
     return mod.__name__.rsplit(".", 1)[-1]
 
 
-def _profile_context(logdir: str):
-    """A ``jax.profiler.trace`` context for one benchmark, or a no-op
-    (with a warning) when the profiler is unavailable on this jaxlib."""
-    import jax
-    try:
-        return jax.profiler.trace(logdir)
-    except Exception as e:                          # pragma: no cover
-        print(f"run.py: profiler unavailable ({e}); continuing unprofiled",
-              file=sys.stderr)
-        return contextlib.nullcontext()
-
-
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         description="Run the benchmark suite (CSV to stdout, JSON reports "
@@ -65,11 +51,6 @@ def main(argv=None) -> int:
         "--only", action="append", default=None, metavar="NAME",
         help="run only this benchmark module (repeatable), e.g. "
              "--only facade_api")
-    parser.add_argument(
-        "--profile", nargs="?", const="profiles", default=None,
-        metavar="DIR",
-        help="capture a jax.profiler trace per benchmark under DIR/<name> "
-             "(default DIR: ./profiles)")
     parser.add_argument(
         "--jsonl", default=None, metavar="PATH",
         help="append every tracker emission (benchmark.report events, "
@@ -109,14 +90,12 @@ def main(argv=None) -> int:
     print("name,us_per_call,derived")
     for mod in mods:
         name = _short(mod)
-        ctx = (_profile_context(os.path.join(args.profile, name))
-               if args.profile else contextlib.nullcontext())
         t0 = time.perf_counter()
         try:
             # the scope tags every emission with bench=<name> (what the
             # per-bench Chrome export filters on); the span makes the
             # benchmark itself the root of any request traces it starts
-            with ctx, tracker.scope(bench=name), \
+            with tracker.scope(bench=name), \
                     obs.spans.start_span("benchmark", tracker=tracker,
                                          bench=name):
                 mod.main()

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.dpp import SubsetBatch
 from ..core.kron import split_indices_multi
 from ..core.krondpp import KronDPP, random_krondpp
@@ -190,16 +191,30 @@ class DPPModel:
                                  "DPP oracle only (k=None); use Local/Mesh "
                                  "for k-DPP draws")
             return self._sample_host(key, n)
-        spec = self.spectrum(cache, runtime=rt)
-        if k is not None:
-            # exact-k draws cannot overflow their k-slot budget
-            return _picks_to_subsets(sample_kdpp_batched(key, spec, int(k),
-                                                         n, runtime=rt))
-        if k_max is None:
-            k_max = spec.suggested_k_max()
-        picks, _, truncated = sample_krondpp_batched(key, spec, int(k_max),
-                                                     n, runtime=rt)
-        return _picks_to_subsets(picks, truncated)
+        # one span per host phase of the call; with the default
+        # NullTracker each is the shared inert span
+        start_span = obs.spans.start_span
+        with start_span("dpp.sample", rows=n) as root:
+            with start_span("dpp.sample.spectrum"):
+                spec = self.spectrum(cache, runtime=rt)
+            with start_span("dpp.sample.k_max"):
+                if k is not None:
+                    # exact-k draws cannot overflow their k-slot budget
+                    k_max = k
+                elif k_max is None:
+                    k_max = spec.suggested_k_max()
+                k_max = int(k_max)
+            root.set_tag("k_max", k_max)
+            with start_span("dpp.sample.draw"):
+                if k is not None:
+                    picks = sample_kdpp_batched(key, spec, k_max, n,
+                                                runtime=rt)
+                    truncated = None
+                else:
+                    picks, _, truncated = sample_krondpp_batched(
+                        key, spec, k_max, n, runtime=rt)
+            with start_span("dpp.sample.pack"):
+                return _picks_to_subsets(picks, truncated)
 
     def _sample_host(self, key: jax.Array, n: int) -> SubsetBatch:
         from ..core.sampling import sample_full_dpp, sample_krondpp

@@ -392,5 +392,7 @@ def phase2_select_pallas(us: jax.Array, k_eff: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((B, 1, K), jnp.int32),
         interpret=interpret,
+        # the kernel's name in the lowered program and the device trace
+        name="phase2_select_pallas",
     )(k_eff.astype(jnp.int32), us.reshape(B, 1, K), G1, Gr)
     return picks[:, 0, :k_max]

@@ -20,6 +20,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .. import obs
+
 
 @dataclasses.dataclass(frozen=True)
 class DualSpectrum:
@@ -62,10 +64,13 @@ class DualSpectrum:
         return self.W * jnp.where(self.lams > 0.0, inv, 0.0)[None, :]
 
     def expected_size(self) -> float:
-        """E|Y| = Σ d/(1+d) = Σ σ(log d) over the r dual eigenvalues."""
+        """E|Y| = Σ d/(1+d) = Σ σ(log d) over the r dual eigenvalues. One
+        device-to-host sync, counted as ``dpp.host_syncs``."""
+        obs.current_tracker().counter("dpp.host_syncs")
         return float(jnp.sum(jax.nn.sigmoid(self.log_eigenvalues())))
 
     def size_std(self) -> float:
+        obs.current_tracker().counter("dpp.host_syncs")
         ll = self.log_eigenvalues()
         p = jax.nn.sigmoid(ll)
         return float(jnp.sqrt(jnp.sum(p * jax.nn.sigmoid(-ll))))

@@ -36,9 +36,8 @@ shared inert span). Turning observability on is one line:
 See the README "Observability" section for the metric namespaces
 (``service.*``, ``spectral_cache.*``, ``learning.*``, ``kernels.*``,
 ``runtime.mesh.*``, ``health.*``), the span model, reading a JSONL run
-log or a Chrome trace, capturing a profiler trace
-(``python -m benchmarks.run --profile``), and the benchmark regression
-gate (``python -m benchmarks.regression``).
+log or a Chrome trace, seeing live spans in a ``jax.profiler`` trace,
+and the benchmark regression gate (``python -m benchmarks.regression``).
 """
 
 from . import export, health, spans

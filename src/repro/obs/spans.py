@@ -32,6 +32,15 @@ or synthesize the record after the fact with ``emit_span``. The
 ``SampleTicket`` pattern is the template: the ticket is stamped with
 ``trace_id``/span id at ``submit()`` and whichever thread runs
 ``flush()`` parents its work on those ids.
+
+Profiler clock: a live ``Span`` also holds a
+``jax.profiler.TraceAnnotation`` of its name from ``__enter__`` to
+``__exit__`` (on the thread that entered it), so under
+``jax.profiler.trace`` every live span is a host event in the
+``.xplane.pb``, on the same clock as the device ops. ``NULL_SPAN`` opens
+no annotation. ``emit_span`` records describe intervals that have
+already ended, so they cannot be annotated: they reach the run log and
+the Chrome export only.
 """
 
 from __future__ import annotations
@@ -81,7 +90,7 @@ class Span:
     and emits the ``event("span", ...)`` record through the tracker."""
 
     __slots__ = ("tracker", "name", "trace_id", "span_id", "parent_id",
-                 "tags", "ts", "_t0", "_token")
+                 "tags", "ts", "_t0", "_token", "_annotation")
 
     def __init__(self, tracker: Tracker, name: str, trace_id: str,
                  parent_id: Optional[str], tags: dict):
@@ -95,6 +104,10 @@ class Span:
         self._t0 = None         # monotonic start, set on enter
 
     def __enter__(self) -> "Span":
+        # imported here so that importing obs does not import jax
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.ts = time.time()
         self._t0 = time.perf_counter()
         self._token = _CURRENT.set(self)
@@ -102,11 +115,17 @@ class Span:
 
     def __exit__(self, *exc) -> bool:
         dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(None, None, None)
         _CURRENT.reset(self._token)
         emit_span(self.tracker, self.name, trace_id=self.trace_id,
                   span_id=self.span_id, parent_id=self.parent_id,
                   ts=self.ts, dur_s=dur, **self.tags)
         return False
+
+    def set_tag(self, key: str, value) -> None:
+        """Add one tag to the record this span emits on exit (for a value
+        known only once the span is open)."""
+        self.tags[key] = value
 
     def __repr__(self) -> str:
         return (f"Span({self.name!r}, trace={self.trace_id}, "
@@ -129,6 +148,9 @@ class _NullSpan:
 
     def __exit__(self, *exc) -> bool:
         return False
+
+    def set_tag(self, key: str, value) -> None:
+        pass
 
 
 NULL_SPAN = _NullSpan()

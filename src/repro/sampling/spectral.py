@@ -86,11 +86,15 @@ class FactorSpectrum:
         return log_product_spectrum(self.lams)
 
     def expected_size(self) -> float:
-        """E|Y| = sum λ/(1+λ) = sum sigmoid(log λ) — overflow-safe."""
+        """E|Y| = sum λ/(1+λ) = sum sigmoid(log λ) — overflow-safe. One
+        device-to-host sync, counted as ``dpp.host_syncs``."""
+        obs.current_tracker().counter("dpp.host_syncs")
         return float(jnp.sum(jax.nn.sigmoid(self.log_eigenvalues())))
 
     def size_std(self) -> float:
-        """sqrt(Var|Y|), Var|Y| = sum p(1-p) with p = λ/(1+λ)."""
+        """sqrt(Var|Y|), Var|Y| = sum p(1-p) with p = λ/(1+λ). One
+        device-to-host sync, counted as ``dpp.host_syncs``."""
+        obs.current_tracker().counter("dpp.host_syncs")
         ll = self.log_eigenvalues()
         p = jax.nn.sigmoid(ll)
         return float(jnp.sqrt(jnp.sum(p * jax.nn.sigmoid(-ll))))

@@ -33,6 +33,29 @@ def _span_events(tracker):
     return [e for e in tracker.events if e["name"] == "span"]
 
 
+#: the spans ``DPPModel.sample`` opens on its device path, root first
+FACADE_SPANS = ("dpp.sample", "dpp.sample.spectrum", "dpp.sample.k_max",
+                "dpp.sample.draw", "dpp.sample.pack")
+
+
+def _profiled(log_dir, fn):
+    """Run ``fn`` under ``jax.profiler`` and return its result and the
+    host events of the trace as (name, start_ns, end_ns)."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        out = fn()
+        jax.block_until_ready(out)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = pathlib.Path(log_dir).rglob("*.xplane.pb")
+    host = [(e.name, e.start_ns, e.end_ns)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host")
+            for line in plane.lines for e in line.events]
+    return out, host
+
+
 # ---------------------------------------------------------------------------
 # primitives
 # ---------------------------------------------------------------------------
@@ -124,6 +147,102 @@ def test_null_tracker_start_span_per_call_overhead_is_bounded():
     # same budget the tracker-primitive no-overhead test pins: the null
     # path must stay an isinstance check + one shared context manager
     assert per_call < 20e-6, f"start_span(null) costs {per_call*1e6:.2f}µs"
+    # what the facade adds to each DPPModel.sample call under the default
+    # process tracker: the root span, its four phase spans, one late tag
+    # and two host-sync counters
+    assert not obs.enabled(obs.current_tracker())
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with spans.start_span("dpp.sample", rows=256) as root:
+            for phase in FACADE_SPANS[1:]:
+                with spans.start_span(phase):
+                    pass
+            root.set_tag("k_max", 29)
+        obs.current_tracker().counter("dpp.host_syncs")
+        obs.current_tracker().counter("dpp.host_syncs")
+    per_call = (time.perf_counter() - t0) / n
+    assert per_call < 20e-6, f"the facade's spans cost {per_call*1e6:.2f}µs"
+
+
+# ---------------------------------------------------------------------------
+# the profiler clock: live spans as host events of a jax.profiler trace
+# ---------------------------------------------------------------------------
+
+def _kron_234():
+    return dpp.random_kron(jax.random.PRNGKey(3), (2, 3, 4)).rescale(3.0)
+
+
+def test_live_span_is_a_host_event_of_the_profiler_trace(tmp_path):
+    t = obs.InMemoryTracker()
+
+    def work():
+        with spans.start_span("outer", tracker=t):
+            with spans.start_span("inner", tracker=t):
+                pass
+            spans.emit_span(t, "after-the-fact", trace_id="tr", ts=0.0,
+                            dur_s=1.0)
+    _, host = _profiled(tmp_path, work)
+    names = [h[0] for h in host]
+    assert names.count("outer") == 1 and names.count("inner") == 1
+    assert "after-the-fact" not in names       # emit_span: no annotation
+    (o,) = [h for h in host if h[0] == "outer"]
+    (i,) = [h for h in host if h[0] == "inner"]
+    assert o[1] <= i[1] and i[2] <= o[2]
+
+
+def test_facade_sample_spans_reach_the_profiler_trace(tmp_path):
+    model = _kron_234()
+    key = jax.random.PRNGKey(7)
+    t = obs.InMemoryTracker()
+    with obs.use(t):
+        _, host = _profiled(tmp_path, lambda: model.sample(key, 8).indices)
+    events = {}
+    for name, start, end in host:
+        if name in FACADE_SPANS:
+            assert name not in events, f"{name} twice"
+            events[name] = (start, end)
+    assert set(events) == set(FACADE_SPANS)
+    lo, hi = events["dpp.sample"]
+    phases = [events[n] for n in FACADE_SPANS[1:]]
+    assert all(lo <= s <= e <= hi for s, e in phases)
+    # the phases follow one another in the facade's order
+    assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))
+    assert t.counters["dpp.host_syncs"] == 2    # expected_size, size_std
+    (root,) = [e for e in _span_events(t) if e["op"] == "dpp.sample"]
+    assert root["rows"] == 8 and root["k_max"] == model.spectrum() \
+        .suggested_k_max()
+    children = {e["op"] for e in _span_events(t)
+                if e["parent"] == root["span"]}
+    assert children == set(FACADE_SPANS[1:])
+
+
+@pytest.mark.parametrize("k", [None, 3])
+def test_facade_draws_are_bit_identical_with_tracing_on_and_off(tmp_path, k):
+    model = _kron_234()
+    key = jax.random.PRNGKey(11)
+    off = model.sample(key, 16, k=k)
+    with obs.use(obs.InMemoryTracker()):
+        on, _ = _profiled(tmp_path, lambda: model.sample(key, 16, k=k))
+    for a, b in ((off.indices, on.indices), (off.mask, on.mask)):
+        assert a.dtype == b.dtype
+        assert (jax.device_get(a) == jax.device_get(b)).all()
+    if k is None:
+        assert (jax.device_get(off.truncated)
+                == jax.device_get(on.truncated)).all()
+
+
+def test_null_tracker_gives_the_facade_no_spans(tmp_path, monkeypatch):
+    model = _kron_234()
+    key = jax.random.PRNGKey(5)
+    model.sample(key, 4)                         # warm: spectrum cached
+
+    def no_span(*a, **kw):
+        raise AssertionError("a Span was built under the NullTracker")
+    monkeypatch.setattr(spans, "Span", no_span)
+    assert not obs.enabled(obs.current_tracker())
+    _, host = _profiled(tmp_path, lambda: model.sample(key, 4).indices)
+    assert not [h for h in host if h[0].startswith("dpp.")]
+    assert spans.current_span() is None
 
 
 # ---------------------------------------------------------------------------

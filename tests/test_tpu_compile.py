@@ -12,6 +12,7 @@ only the one test worker that runs this file loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +70,29 @@ def test_phase2_select_compiles(compile_tpu, batch):
                                                  ke, backend="pallas"),
         ((batch, k), F32), ((batch, 100, k), F32), ((batch, 100, k), F32),
         ((batch,), I32))
+
+
+def test_sampling_draw_names_its_phase2_kernel(compile_tpu, one_chip):
+    """The jitted draw behind ``Kron.sample`` at the paper's size: its
+    fused phase-2 custom call carries the kernel name the device-trace
+    readers look for (``phase2_select_pallas``)."""
+    from repro.sampling.batched import _sample_batched
+    model = dpp.random_kron(jax.random.PRNGKey(0), (100, 100)).rescale(10.0)
+    k = model.spectrum().suggested_k_max()
+
+    def draw(keys, l1, l2, v1, v2):
+        return _sample_batched(keys, (l1, l2), (v1, v2), k)
+    shapes = (((256, 2), jnp.uint32), ((100,), F32), ((100,), F32),
+              ((100, 100), F32), ((100, 100), F32))
+    lowered = jax.jit(draw).lower(*[
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes])
+    calls = [ln for ln in lowered.as_text().splitlines()
+             if "@tpu_custom_call" in ln]
+    assert len(calls) == 1
+    assert 'kernel_name = "phase2_select_pallas"' in calls[0]
+    compiled = compile_tpu(draw, *shapes)
+    assert re.search(r"%phase2_select_pallas[.\d]* = [^\n]*"
+                     r'custom_call_target="tpu_custom_call"', compiled)
 
 
 def test_phase2_select_dense_compiles(compile_tpu):
