@@ -71,10 +71,12 @@ def _place_spectrum(spec: FactorSpectrum,
     Local/Host/None). Uses the mesh's identity-pinned cache: spectrum
     arrays are themselves cached (``SpectralCache``), so repeated
     sampling against one kernel pays the host -> devices broadcast once,
-    not per call."""
+    not per call. The placed copy shares the spectrum's memoized size
+    moments (``dataclasses.replace``)."""
     if runtime is not None and getattr(runtime, "is_mesh", False):
-        return FactorSpectrum(runtime.replicate_pinned(tuple(spec.lams)),
-                              runtime.replicate_pinned(tuple(spec.vecs)))
+        return dataclasses.replace(
+            spec, lams=runtime.replicate_pinned(tuple(spec.lams)),
+            vecs=runtime.replicate_pinned(tuple(spec.vecs)))
     return spec
 
 

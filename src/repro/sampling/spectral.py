@@ -44,6 +44,19 @@ def log_product_spectrum(lams: Tuple[jax.Array, ...]) -> jax.Array:
     return v
 
 
+class _SizeMoments:
+    """Memo of one spectrum's (E|Y|, sd|Y|). A ``FactorSpectrum`` and its
+    placed copies (``dataclasses.replace``) share one, so it is filled
+    once per cached spectrum; the lock makes concurrent first callers
+    compute it once."""
+
+    __slots__ = ("lock", "value")
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.value: Optional[Tuple[float, float]] = None  #: guarded-by: lock
+
+
 @dataclasses.dataclass(frozen=True)
 class FactorSpectrum:
     """Per-factor eigendecompositions of L = L_1 ⊗ ... ⊗ L_m.
@@ -56,6 +69,8 @@ class FactorSpectrum:
     """
     lams: Tuple[jax.Array, ...]
     vecs: Tuple[jax.Array, ...]
+    _moments: _SizeMoments = dataclasses.field(
+        default_factory=_SizeMoments, compare=False, repr=False)
 
     @property
     def m(self) -> int:
@@ -99,13 +114,29 @@ class FactorSpectrum:
         p = jax.nn.sigmoid(ll)
         return float(jnp.sqrt(jnp.sum(p * jax.nn.sigmoid(-ll))))
 
+    def size_moments(self) -> Tuple[float, float]:
+        """(E|Y|, sd|Y|), memoized: the first call runs ``expected_size``
+        and ``size_std`` (their two syncs), later calls on this spectrum
+        or its placed copies read the memo with no device op. Counted as
+        ``spectral_cache.moments_misses`` / ``.moments_hits``."""
+        tracker = obs.current_tracker()
+        memo = self._moments
+        with memo.lock:
+            if memo.value is None:
+                tracker.counter("spectral_cache.moments_misses")
+                memo.value = (self.expected_size(), self.size_std())
+            else:
+                tracker.counter("spectral_cache.moments_hits")
+            return memo.value
+
     def suggested_k_max(self, num_std: float = 6.0) -> int:
         """Static phase-2 budget: E|Y| + num_std·σ, clamped to [1, N].
 
         Samples larger than k_max are truncated (lowest eigen-indices kept);
         at 6σ that is a ~1e-9 event per draw.
         """
-        k = math.ceil(self.expected_size() + num_std * self.size_std()) + 1
+        e, sd = self.size_moments()
+        k = math.ceil(e + num_std * sd) + 1
         return max(1, min(k, self.N))
 
 
@@ -122,7 +153,10 @@ class SpectralCache:
     """LRU cache of per-factor eigendecompositions, keyed on array identity.
 
     ``spectrum(dpp)`` looks up each factor independently, so hits/misses
-    count factor lookups (a 2-factor KronDPP costs two lookups).
+    count factor lookups (a 2-factor KronDPP costs two lookups). It
+    returns one stable ``FactorSpectrum`` per factor tuple while the
+    factors' entries live, so the spectrum's memoized size moments
+    (``suggested_k_max``) are computed once, not on every call.
 
     Thread-safe: one lock guards the LRU map and the hit/miss/eviction
     counters — the serving tier's background flush thread and foreground
@@ -133,6 +167,7 @@ class SpectralCache:
     def __init__(self, maxsize: int = 16):
         self.maxsize = maxsize
         self._entries = collections.OrderedDict()  #: guarded-by: _lock
+        self._spectra = collections.OrderedDict()  #: guarded-by: _lock
         self.hits = 0                              #: guarded-by: _lock
         self.misses = 0                            #: guarded-by: _lock
         self.evictions = 0                         #: guarded-by: _lock
@@ -163,10 +198,15 @@ class SpectralCache:
     def clear(self) -> None:
         with self._lock:
             self._entries.clear()
+            self._spectra.clear()
+
+    @staticmethod
+    def _key(f: jax.Array) -> tuple:
+        return (id(f), tuple(f.shape), str(f.dtype))
 
     def _factor(self, f: jax.Array) -> Tuple[jax.Array, jax.Array]:
         tracker = obs.current_tracker()
-        key = (id(f), tuple(f.shape), str(f.dtype))
+        key = self._key(f)
         with self._lock:
             hit = self._entries.get(key)
             if hit is not None:
@@ -199,15 +239,35 @@ class SpectralCache:
 
     def spectrum(self, dpp: KronDPP) -> FactorSpectrum:
         """FactorSpectrum for a KronDPP — O(sum N_i^3) on miss, O(1) on hit."""
-        pairs = [self._factor(f) for f in dpp.factors]
-        return FactorSpectrum(tuple(p[0] for p in pairs),
-                              tuple(p[1] for p in pairs))
+        return self._spectrum(tuple(dpp.factors))
 
     def spectrum_dense(self, L: jax.Array) -> FactorSpectrum:
         """A dense kernel is the m=1 degenerate case — the whole batched
         pipeline (phase 1/2, k-DPP) works on it unchanged."""
-        lam, vec = self._factor(L)
-        return FactorSpectrum((lam,), (vec,))
+        return self._spectrum((L,))
+
+    def _spectrum(self, factors: Tuple[jax.Array, ...]) -> FactorSpectrum:
+        """The same FactorSpectrum object for the same factor arrays, as
+        long as each factor's eigh entry is the one it was built from (its
+        eigenvalue arrays are the same objects); an evicted and
+        recomputed factor gets a new spectrum, and a new memo.
+        Same LRU bound as the factor entries; the entry pins the factors'
+        ids like ``_factor``'s."""
+        pairs = [self._factor(f) for f in factors]
+        lams = tuple(p[0] for p in pairs)
+        vecs = tuple(p[1] for p in pairs)
+        key = tuple(self._key(f) for f in factors)
+        with self._lock:
+            hit = self._spectra.get(key)
+            if hit is not None and all(
+                    a is b for a, b in zip(hit[1].lams, lams)):
+                self._spectra.move_to_end(key)
+                return hit[1]
+            spec = FactorSpectrum(lams, vecs)
+            self._spectra[key] = (factors, spec)   # strong refs pin the ids
+            while len(self._spectra) > self.maxsize:
+                self._spectra.popitem(last=False)
+            return spec
 
     def spectrum_lowrank(self, V: jax.Array, q: jax.Array
                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:

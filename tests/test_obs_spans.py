@@ -208,6 +208,9 @@ def test_facade_sample_spans_reach_the_profiler_trace(tmp_path):
     # the phases follow one another in the facade's order
     assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))
     assert t.counters["dpp.host_syncs"] == 2    # expected_size, size_std
+    with obs.use(obs.InMemoryTracker()) as again:
+        model.sample(key, 8)
+    assert again.counters.get("dpp.host_syncs", 0) == 0   # memoized k_max
     (root,) = [e for e in _span_events(t) if e["op"] == "dpp.sample"]
     assert root["rows"] == 8 and root["k_max"] == model.spectrum() \
         .suggested_k_max()

@@ -8,15 +8,22 @@ hit/miss behavior, and service coalescing.
 """
 
 import itertools
+import math
+import os
+import pathlib
+import subprocess
+import sys
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import dpp, obs
 from repro.core import KronDPP, random_krondpp, sample_krondpp_batch
 from repro.core.dpp import marginal_kernel
-from repro.sampling import (SamplingService, SpectralCache,
+from repro.sampling import (FactorSpectrum, SamplingService, SpectralCache,
                             compile_cache_size, log_esp_table,
                             picks_to_lists)
 # engine entry points, imported from the submodules (the top-level
@@ -191,6 +198,204 @@ def test_spectral_cache_hit_miss_and_eviction():
     m3 = KronDPP((m2.factors[0], m1.factors[1]))
     cache.spectrum(m3)
     assert cache.stats()["hits"] == 4 and cache.stats()["misses"] == 4
+
+
+# ---------------------------------------------------------------------------
+# the phase-2 budget: size moments memoized with the cached spectrum
+# ---------------------------------------------------------------------------
+
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+MEMO_KINDS = ["kron2", "kron3", "dense"]
+
+
+def _memo_model(kind):
+    if kind == "kron2":
+        return dpp.random_kron(jax.random.PRNGKey(21), (3, 4)).rescale(3.0)
+    if kind == "kron3":
+        return dpp.random_kron(jax.random.PRNGKey(22),
+                               (2, 3, 4)).rescale(3.0)
+    kern = dpp.random_kron(jax.random.PRNGKey(23), (3, 4)).dense_kernel()
+    return dpp.from_kernel(kern).rescale(3.0)
+
+
+def _counted(fn):
+    """fn() under a fresh InMemoryTracker -> (result, counters)."""
+    with obs.use(obs.InMemoryTracker()) as t:
+        out = fn()
+    return out, dict(t.counters)
+
+
+def _assert_memo_miss(counters):
+    assert counters["spectral_cache.moments_misses"] == 1
+    assert counters["dpp.host_syncs"] == 2       # expected_size, size_std
+    assert "spectral_cache.moments_hits" not in counters
+
+
+def _assert_memo_hit(counters):
+    assert counters["spectral_cache.moments_hits"] == 1
+    assert "dpp.host_syncs" not in counters
+    assert "spectral_cache.moments_misses" not in counters
+
+
+@pytest.mark.parametrize("kind", MEMO_KINDS)
+def test_second_sample_reads_the_memoized_k_max(kind):
+    model, cache = _memo_model(kind), SpectralCache()
+    key = jax.random.PRNGKey(0)
+    first, c = _counted(lambda: model.sample(key, 4, cache=cache))
+    _assert_memo_miss(c)
+    second, c = _counted(lambda: model.sample(key, 4, cache=cache))
+    _assert_memo_hit(c)
+    assert first.indices.shape == second.indices.shape
+
+
+@pytest.mark.parametrize("kind", MEMO_KINDS)
+def test_memoized_k_max_equals_a_fresh_eager_computation(kind):
+    model, cache = _memo_model(kind), SpectralCache()
+    k_first = model.spectrum(cache).suggested_k_max()
+    spec = model.spectrum(cache)
+    ll = spec.log_eigenvalues()
+    e = float(jnp.sum(jax.nn.sigmoid(ll)))
+    sd = float(jnp.sqrt(jnp.sum(jax.nn.sigmoid(ll) * jax.nn.sigmoid(-ll))))
+    fresh = FactorSpectrum(spec.lams, spec.vecs)        # its own cold memo
+    assert spec.size_moments() == (e, sd) \
+        == (fresh.expected_size(), fresh.size_std())
+    want = max(1, min(math.ceil(e + 6.0 * sd) + 1, model.N))
+    assert k_first == spec.suggested_k_max() == fresh.suggested_k_max() \
+        == want
+    assert model.sample(jax.random.PRNGKey(1), 2,
+                        cache=cache).indices.shape[1] == want
+
+
+def test_rescaled_model_misses_the_memo():
+    model, cache = _memo_model("kron2"), SpectralCache()
+    key = jax.random.PRNGKey(2)
+    model.sample(key, 4, cache=cache)
+    scaled = model.rescale(2.0, cache=cache)        # new factor arrays
+    _, c = _counted(lambda: scaled.sample(key, 4, cache=cache))
+    _assert_memo_miss(c)
+    _, c = _counted(lambda: model.sample(key, 4, cache=cache))
+    _assert_memo_hit(c)
+
+
+@pytest.mark.parametrize("drop", ["clear", "evict"])
+def test_clear_and_lru_eviction_drop_the_memo(drop):
+    model, cache = _memo_model("kron2"), SpectralCache(maxsize=2)
+    key = jax.random.PRNGKey(3)
+    model.sample(key, 4, cache=cache)
+    if drop == "clear":
+        cache.clear()
+        assert not cache._spectra            # no factor stays pinned
+    else:                    # two fresh factors push the model's out
+        _memo_model("dense").sample(key, 4, cache=cache)
+        _memo_model("kron3").sample(key, 4, cache=cache)
+        assert len(cache._spectra) <= cache.maxsize
+    _, c = _counted(lambda: model.sample(key, 4, cache=cache))
+    _assert_memo_miss(c)
+
+
+@pytest.mark.parametrize("kind", MEMO_KINDS)
+def test_draws_are_bit_identical_with_the_memo_cold_and_warm(kind):
+    model, cache = _memo_model(kind), SpectralCache()
+    key = jax.random.PRNGKey(9)
+    cold = model.sample(key, 16, cache=cache)
+    warm = model.sample(key, 16, cache=cache)
+    other = model.sample(key, 16, cache=SpectralCache())
+    for b in (warm, other):
+        for x, y in ((cold.indices, b.indices), (cold.mask, b.mask),
+                     (cold.truncated, b.truncated)):
+            assert x.dtype == y.dtype
+            assert (np.asarray(x) == np.asarray(y)).all()
+
+
+@pytest.mark.threaded
+def test_threads_sampling_one_fresh_model_fill_the_memo_once():
+    model, cache = _memo_model("kron3"), SpectralCache()
+    n = 8
+    barrier = threading.Barrier(n)
+    widths, errors = [], []
+
+    def draw(seed):
+        try:
+            barrier.wait(timeout=60)
+            out = model.sample(jax.random.PRNGKey(seed), 4, cache=cache)
+            widths.append(int(out.indices.shape[1]))
+        except Exception as e:    # noqa: BLE001 — surfaced below
+            errors.append(e)
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with obs.use(obs.InMemoryTracker()) as t:
+            threads = [threading.Thread(target=draw, args=(s,))
+                       for s in range(n)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=120)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(th.is_alive() for th in threads)
+    assert errors == [] and len(widths) == n
+    assert set(widths) == {model.spectrum(cache).suggested_k_max()}
+    # the memo's lock lets one thread compute; the others read its result
+    assert t.counters["spectral_cache.moments_misses"] == 1
+    assert t.counters["spectral_cache.moments_hits"] == n - 1
+    assert t.counters["dpp.host_syncs"] == 2
+
+
+def test_null_tracker_warm_sample_runs_no_moment_arithmetic(monkeypatch):
+    model, cache = _memo_model("kron2"), SpectralCache()
+    key = jax.random.PRNGKey(4)
+    cold = model.sample(key, 8, cache=cache)
+
+    def no_sync(self):
+        raise AssertionError("a warm sample recomputed a size moment")
+
+    monkeypatch.setattr(FactorSpectrum, "expected_size", no_sync)
+    monkeypatch.setattr(FactorSpectrum, "size_std", no_sync)
+    assert not obs.enabled(obs.current_tracker())
+    warm = model.sample(key, 8, cache=cache)
+    assert (np.asarray(cold.indices) == np.asarray(warm.indices)).all()
+
+
+def _mesh_memo_checks():
+    """Shared body: a Mesh draw's placed spectrum carries the memo."""
+    assert jax.device_count() >= 2, jax.device_count()
+    rt = dpp.Mesh(axes={"data": 2})
+    model, cache = _memo_model("kron2"), SpectralCache()
+    key = jax.random.PRNGKey(6)
+    first, c = _counted(lambda: model.sample(key, 8, runtime=rt,
+                                             cache=cache))
+    _assert_memo_miss(c)
+    again, c = _counted(lambda: model.sample(key, 8, runtime=rt,
+                                             cache=cache))
+    _assert_memo_hit(c)
+    local, c = _counted(lambda: model.sample(key, 8, cache=cache))
+    _assert_memo_hit(c)                 # placed and local share one memo
+    for b in (again, local):
+        assert (np.asarray(first.indices) == np.asarray(b.indices)).all()
+
+
+@pytest.mark.skipif(jax.device_count() < 8,
+                    reason="needs >= 8 devices (the CI mesh job)")
+def test_mesh_sample_hits_the_memo_in_process():
+    _mesh_memo_checks()
+
+
+@pytest.mark.skipif(jax.device_count() >= 8,
+                    reason="already covered by the in-process variant")
+def test_mesh_sample_hits_the_memo_subprocess():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",   # never the chip
+               XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               PYTHONPATH=str(ROOT / "src") + os.pathsep
+               + str(ROOT / "tests"))
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import test_sampling_batched as t; t._mesh_memo_checks(); "
+         "print('MESH_MEMO_OK')"],
+        capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "MESH_MEMO_OK" in out.stdout
 
 
 def test_one_compile_per_shape():
