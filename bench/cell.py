@@ -116,6 +116,53 @@ def _compile_counter():
     return state
 
 
+def passes(checks: dict) -> bool:
+    """``correct``: every number compared is within its limit."""
+    return all(c["value"] <= c["limit"] for c in checks.values())
+
+
+def use_compile_cache() -> None:
+    """Point JAX's persistent compilation cache at ``CACHE_DIR`` and keep
+    every program there; call before JAX compiles anything."""
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
+    os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
+
+
+def run_window(drv, seconds: float, devices, compiles: dict,
+               trace: bool):
+    """The measured window: ``drv.run`` for ``seconds`` with ``compiles``
+    on. With ``trace``, under the profiler and with an
+    ``obs.InMemoryTracker`` installed; returns ``(win, tracker,
+    DeviceTrace of devices)``, and ``(win, None, None)`` without."""
+    import jax
+    from repro import obs
+    from bench import trace as trace_mod
+    annotate = lambda name: contextlib.nullcontext()       # noqa: E731
+    if trace:
+        tracker = obs.InMemoryTracker()
+        log_dir = tempfile.mkdtemp(prefix="bench-trace-")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+        prev = obs.configure(tracker)
+        annotate = jax.profiler.TraceAnnotation
+    compiles["on"] = True
+    try:
+        win = drv.run(seconds, annotate)
+    finally:
+        compiles["on"] = False
+        if trace:
+            obs.configure(prev)
+            jax.profiler.stop_trace()
+    if not trace:
+        return win, None, None
+    tr = trace_mod.DeviceTrace.from_dir(log_dir, win["window_s"],
+                                        devices=[d.id for d in devices])
+    shutil.rmtree(log_dir, ignore_errors=True)
+    return win, tracker, tr
+
+
 def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
     """Run one cell. ``root`` is the checkout that holds
     ``BENCHMARK.json``; tests pass another and ``require_tpu=False``."""
@@ -126,9 +173,7 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
 
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
-    os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
-    os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
+    use_compile_cache()
     sys.path.insert(0, str(ROOT / "src"))
     sys.path.insert(0, str(ROOT))
     spec = json.loads((root / "BENCHMARK.json").read_text())
@@ -141,31 +186,14 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
               f"JAX finds {len(devices)} {devices[0].platform} device(s)",
               file=sys.stderr)
         return 2
-    from repro import obs
-    from bench import trace as trace_mod
 
     compiles = _compile_counter()
     drv = cell.driver.Driver(cell, args.seed, devices[: cell.chips])
     drv.setup()
     setup_s = time.perf_counter() - T_START
 
-    tracker = obs.InMemoryTracker() if args.trace else None
-    log_dir = tempfile.mkdtemp(prefix="bench-trace-") if args.trace else None
-    annotate = jax.profiler.TraceAnnotation if args.trace else \
-        (lambda name: contextlib.nullcontext())
-    if args.trace:
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(log_dir, profiler_options=opts)
-        prev = obs.configure(tracker)
-    compiles["on"] = True
-    try:
-        win = drv.run(args.seconds, annotate)
-    finally:
-        compiles["on"] = False
-        if args.trace:
-            obs.configure(prev)
-            jax.profiler.stop_trace()
+    win, tracker, tr = run_window(drv, args.seconds, devices[: cell.chips],
+                                  compiles, bool(args.trace))
     peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices[: cell.chips])
     drv.release()
@@ -177,10 +205,6 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
               "memory_peak_bytes": peak}
     metrics, breakdown = {}, None
     if args.trace:
-        tr = trace_mod.DeviceTrace.from_dir(
-            log_dir, win["window_s"],
-            devices=[d.id for d in devices[: cell.chips]])
-        shutil.rmtree(log_dir, ignore_errors=True)
         device.update(busy_s=tr.busy_s, window_s=tr.window_s)
         reading = Reading(tr, tracker, win.get("work", {}),
                           devices[0].device_kind)
@@ -200,7 +224,7 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
     print(json.dumps({"window": win.get("info", {}),
                       "compiles_in_window": compiles["names"],
                       "setup_s": setup_s}), file=sys.stderr)
-    correct = all(c["value"] <= c["limit"] for c in checks.values())
+    correct = passes(checks)
     for name, c in checks.items():
         print(f"check {name} {c['value']!r} limit {c['limit']!r} "
               f"{'ok' if c['value'] <= c['limit'] else 'FAIL'}",

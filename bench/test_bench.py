@@ -32,8 +32,13 @@ from bench import cell, control, counts, trace            # noqa: E402
 TINY_CONFIGS = {
     "kron-tiny": {"sizes": [8, 8], "expected_size": 4.0,
                   "dtype": "float32", "runtime": {"kind": "local"}},
+    # the tiny learning cell states the default matmul precision, whose
+    # control (the bfloat16 reference) fails the chip's limits at this
+    # size; the "highest" of the real cell and its control are checked
+    # by test_learning_control_at_high_reads_above_the_program
     "krk-tiny": {"sizes": [6, 6], "expected_size": 3.0, "subsets": 64,
                  "subset_width": 8, "dtype": "float32",
+                 "matmul_precision": "default",
                  "runtime": {"kind": "local"}},
 }
 TINY_TRAFFIC = {
@@ -65,7 +70,7 @@ def tiny_root(tmp_path):
     (tmp_path / "bench" / "configs").mkdir()
     configs = []
     for name, cfg in TINY_CONFIGS.items():
-        keys = ("ll_gap", "factor_gap") if "subsets" in cfg else \
+        keys = ("ll_gap", "factor_rel_gap") if "subsets" in cfg else \
             ("phase1_gap", "phase2_gap")
         cfg = dict(cfg, limits={k: limits[k] for k in keys})
         path = tmp_path / "bench" / "configs" / f"{name}.json"
@@ -155,6 +160,20 @@ def test_counts_by_hand():
     assert (share, bound) == (pytest.approx(2e-3), "compute")
 
 
+def test_sweep_counts_by_hand():
+    # factors 2 x 2 and 3 x 3, subsets of 1, 2 and 2 items. A Theta pass:
+    # k^3 + 5k^2 per subset = 6 + 28 + 28 = 62, twice a sweep = 124; the
+    # eighs 4/3 (8 + 27) = 140/3
+    assert counts.sweep_flops((2, 3), [1, 2, 2]) == pytest.approx(
+        124 + 140 / 3)
+    # per pass: 5 item indices, both factors read (4 + 9) and A, C
+    # written (4 + 9): 31 words of 4 bytes, twice a sweep
+    assert counts.sweep_bytes((2, 3), [1, 2, 2]) == 2 * 4 * 31
+    # an empty slot costs nothing
+    assert counts.sweep_flops((2, 3), [0, 1, 2, 2]) == \
+        counts.sweep_flops((2, 3), [1, 2, 2])
+
+
 # -- a new cell and metric are data ------------------------------------------
 
 def test_new_cell_and_metric_are_files(tiny_root, capsys):
@@ -213,6 +232,27 @@ def test_program_passes_and_control_fails(tiny_root, workload):
     assert any(v["value"] > v["limit"] for v in ctrl.values()), ctrl
 
 
+def test_learning_control_at_high_reads_above_the_program(tiny_root):
+    """The control of a float32-at-highest configuration, the reference
+    with every intermediate held to two bfloat16 terms, reads a factor
+    gap at least three times the program's. The chip's limit was set
+    from full-size readings on the chip (PERF.md)."""
+    import contextlib
+    import jax
+    path = tiny_root / "bench" / "configs" / "krk-tiny.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                    matmul_precision="highest")))
+    spec = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    c = cell.Cell("learn-tiny", spec, tiny_root)
+    drv = c.driver.Driver(c, 11, jax.devices()[:1])
+    drv.setup()
+    drv.run(0.5, lambda name: contextlib.nullcontext())
+    drv.release()
+    program, ctrl = drv.check(), drv.check(control=True)
+    assert (ctrl["factor_rel_gap"]["value"]
+            >= 3 * program["factor_rel_gap"]["value"])
+
+
 def test_serving_setup_warms_every_flush_size(tiny_root):
     """A flush drains whole requests until it holds max_batch rows, so it
     can overshoot to max_batch + 3 rows (padded to 128): set-up compiles
@@ -257,19 +297,10 @@ def _alter_served_row(monkeypatch):
     monkeypatch.setattr(SamplingService, "draw_keyed", altered)
 
 
-def _state_unchanged(monkeypatch):
-    import jax.numpy as jnp
-    from repro.learning import engine
-
-    def frozen(self, params, sub, a_trial):
-        return params, a_trial, jnp.zeros((), jnp.int32)
-    monkeypatch.setattr(engine.LearningEngine, "_krk_sweep", frozen)
-
-
 FAULTS = {
     "altered_pick": ("sample-tiny", _alter_first_pick),
     "altered_served_row": ("serve-tiny", _alter_served_row),
-    "state_unchanged": ("learn-tiny", _state_unchanged),
+    "state_unchanged": ("learn-tiny", None),
     "half_batch": ("learn-tiny", None),
 }
 
@@ -288,6 +319,52 @@ def test_a_planted_fault_is_not_correct(tiny_root, capsys, monkeypatch,
         plant(monkeypatch)
         out = run_cell(tiny_root, workload, capsys)
     assert out["correct"] is False, out["checks"]
+
+
+# -- every cell of BENCHMARK.json resolves -----------------------------------
+
+SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+TINY_OF_KIND = {"closed_sample": "sample-tiny", "open_serve": "serve-tiny",
+                "fit_loop": "learn-tiny"}
+
+
+@pytest.mark.parametrize("workload", [w["name"] for w in SPEC["workloads"]])
+def test_every_cell_resolves(tiny_root, workload):
+    """The cell's configuration, traffic, driver and readers are files
+    that load; a tiny cell of its driver's kind yields every end-to-end
+    metric that applies to it, and each of its per-layer readers reads
+    that window's counters and work without an error."""
+    import contextlib
+    import jax
+    from repro import obs
+    c = cell.Cell(workload, SPEC)
+    w = {x["name"]: x for x in SPEC["workloads"]}[workload]
+    cfg = {x["name"]: x for x in SPEC["configs"]}[w["config"]]
+    assert (ROOT / cfg["file"]).is_file()
+    assert hasattr(c.driver, "Driver")
+    readers = {m["name"]: cell.load_module(c.metrics_dir / f"{m['name']}.py")
+               for m in c.per_layer}
+    assert readers and all(hasattr(r, "read") for r in readers.values())
+    names = {m["name"] for m in c.end_to_end}
+    assert "setup_s" in names and len(names) >= 2
+
+    tiny_spec = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    tiny = cell.Cell(TINY_OF_KIND[c.traffic["kind"]], tiny_spec, tiny_root)
+    drv = tiny.driver.Driver(tiny, 13, jax.devices()[:1])
+    drv.setup()
+    tracker = obs.InMemoryTracker()
+    prev = obs.configure(tracker)
+    try:
+        win = drv.run(0.5, lambda name: contextlib.nullcontext())
+    finally:
+        obs.configure(prev)
+    drv.release()
+    assert names - {"setup_s"} <= set(win["metrics"])
+    busy = trace.DeviceTrace({0: [("fusion.1", 0, 10 ** 9)]}, 2.0)
+    reading = cell.Reading(busy, tracker, win.get("work", {}), "TPU v5 lite")
+    for name, reader in readers.items():
+        value = reader.read(reading)
+        assert value is None or np.isfinite(value), name
 
 
 def test_no_result_without_a_chip(capsys):

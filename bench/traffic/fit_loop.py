@@ -4,8 +4,9 @@ Traffic parameters: ``iters`` and ``log_every`` (sweeps per fit, sweeps
 per compiled chunk) and ``check_fits`` (how many of the window's fits,
 drawn from the seed, are compared with the float64 reference). Every
 call is ``Kron.fit(batch, algorithm="krk", iters, log_every,
-schedule=armijo())`` on one chip (``Local()``), at JAX's default matmul
-precision.
+schedule=armijo())`` on one chip (``Local()``), under
+``jax.default_matmul_precision`` of the configuration's
+``matmul_precision``.
 
 The configuration fixes the data: a planted Kron model (seeded paper
 Sec. 5.1 factors at E|Y| = ``expected_size``), ``subsets`` exact draws
@@ -32,11 +33,12 @@ class Driver:
 
     def _fit(self):
         from repro import dpp
-        rep = self.model.fit(self.batch, algorithm="krk",
-                             iters=self.tr["iters"],
-                             log_every=self.tr["log_every"],
-                             schedule=dpp.schedules.armijo(),
-                             runtime=dpp.Local())
+        with jax.default_matmul_precision(self.cfg["matmul_precision"]):
+            rep = self.model.fit(self.batch, algorithm="krk",
+                                 iters=self.tr["iters"],
+                                 log_every=self.tr["log_every"],
+                                 schedule=dpp.schedules.armijo(),
+                                 runtime=dpp.Local())
         jax.block_until_ready(rep.model.factors)
         return rep
 
@@ -64,12 +66,20 @@ class Driver:
         window_s = time.perf_counter() - t0
         self.reps = reps
         sweeps = sum(r.sweeps for r in reps)
+        self.work = {"sweeps": sweeps}
         return {"attempted": len(reps), "failed": 0, "window_s": window_s,
                 "metrics": {"krk_sweeps_per_s": sweeps / window_s},
+                "work": self.work,
                 "info": {"fits": len(reps), "sweeps": sweeps,
                          "final_ll": reps[-1].log_likelihoods[-1]}}
 
     def release(self) -> None:
+        # the sizes of the window's sweeps, for their operation count
+        # (bench/counts.py)
+        mask = np.asarray(self.batch.mask)
+        self.work.update(factor_sizes=tuple(self.cfg["sizes"]),
+                         subset_sizes=mask.sum(1), subsets=mask.shape[0],
+                         width=mask.shape[1])
         rng = np.random.default_rng(self.seed)
         picked = rng.choice(len(self.reps),
                             min(self.tr["check_fits"], len(self.reps)),
@@ -83,20 +93,21 @@ class Driver:
 
     def check(self, control: bool = False) -> dict:
         """The numbers compared, each with its limit; with ``control``
-        the reference computed in bfloat16 stands in for the program."""
+        the reference computed in the precision below the
+        configuration's (``krk_ref.BELOW``) stands in for the program."""
         n1, n2 = self.cfg["sizes"]
         subsets = krk_ref.Subsets(*self.host_batch, n1, n2)
         want, want_lls, _ = krk_ref.fit(self.host_start, subsets,
                                         self.tr["iters"])
         got = self.got
         if control:
+            rnd = krk_ref.BELOW[self.cfg["matmul_precision"]]
             f, lls, _ = krk_ref.fit(self.host_start, subsets,
-                                    self.tr["iters"], rnd=krk_ref.bf16)
+                                    self.tr["iters"], rnd=rnd)
             got = [(f, lls)]
-        worst = {"ll_gap": 0.0, "factor_gap": 0.0}
+        worst = {"ll_gap": 0.0, "factor_rel_gap": 0.0}
         for factors, lls in got:
-            gaps = krk_ref.compare(self.host_start, factors, lls, want,
-                                   want_lls)
+            gaps = krk_ref.compare(factors, lls, want, want_lls)
             worst = {k: max(worst[k], gaps[k]) for k in worst}
         lim = self.cfg["limits"]
         return {k: {"value": v, "limit": lim[k]} for k, v in worst.items()}

@@ -15,7 +15,12 @@ exponential distribution, tenants and request sizes come in equal
 counts, and the seed permutes each. One thread submits each request at
 its due time; a request is timed from when it was due to when its
 ticket resolves, so a late submit counts against the server. How late
-the submits ran is reported beside the result.
+the submits ran is reported beside the result. A resolved ticket is
+dropped at once, as a front end drops a request it has answered: the
+window keeps its resolve time, whether its size was right, and the rows
+of the ``check_requests`` requests drawn from the seed beforehand, so
+that what the window holds for the check does not grow the heap that
+the collector scans.
 """
 
 from __future__ import annotations
@@ -44,24 +49,38 @@ def schedule(rate: float, seconds: float, tenants, rows, seed: int):
 
 class _Collector(threading.Thread):
     """Records when each ticket resolves: waits on the oldest pending
-    ticket, then sweeps every pending ticket that is done."""
+    ticket, then sweeps every pending ticket that is done. Of a resolved
+    ticket it keeps the resolve time, counts a wrong number of rows, and
+    keeps the rows only of the requests in ``keep``."""
 
-    def __init__(self):
+    def __init__(self, keep):
         super().__init__(name="bench-collector", daemon=True)
         self.cond = threading.Condition()
-        self.pending = []                     # [(index, ticket)]
-        self.done_at = {}
+        self.pending = []                     # [(index, ticket, rows)]
+        self.keep = keep
+        self.done_at, self.kept = {}, {}
+        self.wrong = 0
         self.closed = False
 
-    def add(self, i, ticket):
+    def add(self, i, ticket, rows):
         with self.cond:
-            self.pending.append((i, ticket))
+            self.pending.append((i, ticket, rows))
             self.cond.notify()
 
     def close(self):
         with self.cond:
             self.closed = True
             self.cond.notify()
+
+    def _resolved(self, i, ticket, rows, now):
+        try:
+            got = ticket.result(timeout=0)
+        except Exception:                     # noqa: BLE001 — a failed
+            return                            # request is counted, not raised
+        self.done_at[i] = now
+        self.wrong += len(got) != rows
+        if i in self.keep:
+            self.kept[i] = (ticket.tenant, ticket.seq, rows, got)
 
     def run(self):
         while True:
@@ -78,11 +97,11 @@ class _Collector(threading.Thread):
             now = time.perf_counter()
             with self.cond:
                 keep = []
-                for i, t in self.pending:
+                for i, t, rows in self.pending:
                     if t.done():
-                        self.done_at[i] = now
+                        self._resolved(i, t, rows, now)
                     else:
-                        keep.append((i, t))
+                        keep.append((i, t, rows))
                 self.pending = keep
 
 
@@ -127,9 +146,12 @@ class Driver:
         due, who, size = schedule(self.tr["rate_rps"], seconds,
                                   self.tr["tenants"], self.tr["rows"],
                                   self.seed)
-        col = _Collector()
+        rng = np.random.default_rng(self.seed)
+        col = _Collector(set(rng.choice(
+            len(due), min(self.tr["check_requests"], len(due)),
+            replace=False).tolist()))
         col.start()
-        tickets, late, refused = {}, np.zeros(len(due)), 0
+        late, refused = np.zeros(len(due)), 0
         t0 = time.perf_counter()
         for i, (d, t, s) in enumerate(zip(due, who, size)):
             wait = t0 + d - time.perf_counter()
@@ -139,32 +161,23 @@ class Driver:
             late[i] = time.perf_counter() - (t0 + d)
             try:
                 with annotate("bench.submit"):
-                    tickets[i] = self.svc.submit(s, tenant=t)
+                    ticket = self.svc.submit(s, tenant=t)
             except (QueueFull, ServiceClosed):
                 refused += 1
                 continue
-            col.add(i, tickets[i])
+            col.add(i, ticket, s)
         window_s = time.perf_counter() - t0
         limit = time.perf_counter() + self.tr["drain_s"]
         while col.pending and time.perf_counter() < limit:
             time.sleep(0.01)
         col.close()
         col.join(5.0)
-        lat, failed = [], refused
-        self.served = []
-        for i, tk in tickets.items():
-            if i not in col.done_at:
-                failed += 1
-                continue
-            try:
-                rows = tk.result(timeout=0)
-            except Exception:                 # noqa: BLE001 — a failed
-                failed += 1                   # request is counted, not
-                continue                      # raised
-            lat.append(col.done_at[i] - (t0 + due[i]))
-            self.served.append((tk.tenant, tk.seq, size[i], rows))
+        done = sorted(col.done_at)
+        failed = len(due) - len(done)
+        self.wrong, self.kept = col.wrong, col.kept
         # in due order, for the knee sweep's backlog test
-        self.last_latencies = lat = np.asarray(lat)
+        self.last_latencies = lat = np.asarray(
+            [col.done_at[i] - (t0 + due[i]) for i in done])
         p95 = 1e3 * float(np.percentile(lat, 95)) if len(lat) else \
             float("inf")
         return {"attempted": len(due), "failed": failed,
@@ -188,11 +201,7 @@ class Driver:
         """The numbers compared, each with its limit; with ``control``
         the bfloat16 reference stands in for the served rows."""
         checker = draw_ref.RowChecker(self.spectra)
-        wrong = sum(1 for _, _, n, rows in self.served if len(rows) != n)
-        rng = np.random.default_rng(self.seed)
-        k = min(self.tr["check_requests"], len(self.served))
-        picked = [self.served[i] for i in
-                  sorted(rng.choice(len(self.served), k, replace=False))]
+        picked = [self.kept[i] for i in sorted(self.kept)]
         triples = [(t, s, j) for t, s, n, rows in picked
                    for j in range(len(rows))]
         rows = [r for _, _, _, rs in picked for r in rs]
@@ -200,7 +209,7 @@ class Driver:
             checker, draw_ref.served_row_keys(self.service_seed, triples),
             rows, control)
         lim = self.cfg["limits"]
-        return {"wrong_size_requests": {"value": wrong, "limit": 0},
+        return {"wrong_size_requests": {"value": self.wrong, "limit": 0},
                 "phase1_gap": {"value": got["phase1_gap"],
                                "limit": lim["phase1_gap"]},
                 "phase2_gap": {"value": got["phase2_gap"],
