@@ -4,6 +4,8 @@ Full kernel:   O(N^3 + N k^3)   (eigendecomposition dominates)
 KronDPP m=2:   O(N^{3/2} + N k^3)
 KronDPP m=3:   O(N + N k^3) = O(N k^3)
 
+``sample_kdpp`` is the k-DPP oracle (Kulesza & Taskar 2011, Alg. 1): its
+phase 1 is the float64 log-space ESP draw in place of the Bernoulli one.
 The phase-2 selection loop is shared. It is a host-side sampler that runs
 eagerly with numpy-style control flow; the per-step linear algebra is jax.
 
@@ -93,8 +95,13 @@ def sample_krondpp(rng: np.random.Generator, dpp: KronDPP) -> List[int]:
     if len(J) == 0:
         return []
 
-    # Lazily build selected eigenvectors: v_(i1..im) = kron(v1_i1, ..., vm_im)
-    sizes = [f.shape[0] for f in dpp.factors]
+    return _phase2_select(rng, _kron_columns(vecs, J))
+
+
+def _kron_columns(vecs: Sequence[np.ndarray], J: np.ndarray) -> np.ndarray:
+    """The eigenvectors of L_1 ⊗ ... ⊗ L_m at product indices J (row-major),
+    built lazily: v_(i1..im) = kron(v1_i1, ..., vm_im). (N, |J|)."""
+    sizes = [v.shape[0] for v in vecs]
     cols = []
     for g in J:
         parts = []
@@ -107,8 +114,60 @@ def sample_krondpp(rng: np.random.Generator, dpp: KronDPP) -> List[int]:
         for k in range(1, len(sizes)):
             v = np.outer(v, vecs[k][:, parts[k]]).reshape(-1)
         cols.append(v)
-    V = np.stack(cols, axis=1)
-    return _phase2_select(rng, V)
+    return np.stack(cols, axis=1)
+
+
+def log_esp_table(log_lam: np.ndarray, k: int) -> np.ndarray:
+    """float64 log e_j(λ_1..λ_n) for n = 0..N, j = 0..k — shape (N+1, k+1),
+    by the recursion e_j^n = e_j^{n-1} + λ_n e_{j-1}^{n-1} in log space
+    (-inf entries of ``log_lam``, zero eigenvalues, add nothing)."""
+    T = np.full((log_lam.shape[0] + 1, k + 1), -np.inf)
+    T[:, 0] = 0.0
+    for n, ll in enumerate(log_lam, start=1):
+        T[n, 1:] = np.logaddexp(T[n - 1, 1:], T[n - 1, :-1] + ll)
+    return T
+
+
+def sample_kdpp(rng: np.random.Generator, factors: Sequence[np.ndarray],
+                k: int, num_samples: int = 1) -> List[List[int]]:
+    """``num_samples`` k-DPP draws (Kulesza & Taskar 2011, Alg. 1) from
+    L = L_1 ⊗ ... ⊗ L_m (m = 1 is a dense kernel), in float64.
+
+    Each factor is eigendecomposed once, in float64; its eigenvalues at
+    or below its rounding level (size x the machine epsilon of the
+    factor's own dtype x the largest) count as zero. Phase 1 goes over
+    the product spectrum from the last eigenvalue to the first and keeps
+    eigenvalue n with probability λ_n e_{k'-1}(λ_1..λ_{n-1}) /
+    e_{k'}(λ_1..λ_n), k' being the number still to keep; the ESP table is
+    built once for all draws. Below rank, where |Y| = k has probability
+    0, a draw keeps all rank eigenvectors, as the device sampler does.
+    Phase 2 is the projection-DPP chain rule on the kept columns.
+    """
+    log_lam, vecs = np.zeros(1), []
+    for f in factors:
+        f = np.asarray(f)
+        lam, vec = np.linalg.eigh(f.astype(np.float64))
+        tol = lam.shape[0] * np.finfo(f.dtype).eps * np.abs(lam).max()
+        with np.errstate(divide="ignore"):
+            ll = np.where(lam > tol, np.log(np.maximum(lam, tol)), -np.inf)
+        log_lam = (log_lam[:, None] + ll[None, :]).reshape(-1)
+        vecs.append(vec)
+    table = log_esp_table(log_lam, k)
+    k0 = min(int(k), int(np.isfinite(log_lam).sum()))
+    out = []
+    for _ in range(num_samples):
+        J, k_rem = [], k0
+        for n in range(log_lam.shape[0], 0, -1):
+            if k_rem == 0:
+                break
+            log_p = log_lam[n - 1] + table[n - 1, k_rem - 1] - table[n, k_rem]
+            if rng.random() < np.exp(min(log_p, 0.0)):
+                J.append(n - 1)
+                k_rem -= 1
+        J = np.array(J[::-1], np.int64)
+        out.append(_phase2_select(rng, _kron_columns(vecs, J))
+                   if len(J) else [])
+    return out
 
 
 def sample_krondpp_batch(key: jax.Array, dpp: KronDPP, num_samples: int,

@@ -174,8 +174,9 @@ class DPPModel:
             device call for the whole batch; ``Mesh(axes={"data": n})`` —
             the same pipeline with the key batch sharded over the mesh
             (draws match Local bit-for-bit on shared keys); ``Host()`` —
-            the numpy reference oracle (k=None only), one eigh + one
-            subset per draw.
+            the float64 numpy reference oracle, one eigh + one subset per
+            draw for the DPP, one eigh + one ESP table per call for the
+            k-DPP (``core.sampling.sample_kdpp``).
         k_max: static phase-2 budget override for the device DPP path
             (defaults to the spectrum's E|Y| + 6σ bound).
         backend: deprecated placement strings ("device"/"host"), shimmed
@@ -188,15 +189,11 @@ class DPPModel:
         for s in shape:
             n *= int(s)
         if rt.kind == "host":
-            if k is not None:
-                raise ValueError("the Host runtime implements the plain "
-                                 "DPP oracle only (k=None); use Local/Mesh "
-                                 "for k-DPP draws")
-            return self._sample_host(key, n)
+            return self._sample_host(key, n, k)
         # one span per host phase of the call; with the default
         # NullTracker each is the shared inert span
         start_span = obs.spans.start_span
-        with start_span("dpp.sample", rows=n) as root:
+        with start_span("dpp.sample", rows=n, k=k) as root:
             with start_span("dpp.sample.spectrum"):
                 spec = self.spectrum(cache, runtime=rt)
             with start_span("dpp.sample.k_max"):
@@ -218,18 +215,28 @@ class DPPModel:
             with start_span("dpp.sample.pack"):
                 return _picks_to_subsets(picks, truncated)
 
-    def _sample_host(self, key: jax.Array, n: int) -> SubsetBatch:
-        from ..core.sampling import sample_full_dpp, sample_krondpp
+    def _sample_host(self, key: jax.Array, n: int,
+                     k: Optional[int] = None) -> SubsetBatch:
+        from ..core.sampling import (sample_full_dpp, sample_kdpp,
+                                     sample_krondpp)
         seed = int(jax.random.randint(key, (), 0, np.iinfo(np.int32).max))
         rng = np.random.default_rng(seed)
-        if self.m == 1:
-            subs = [sample_full_dpp(rng, np.asarray(self.factors[0]))
+        factors = self._host_factors()
+        if k is not None:
+            subs = sample_kdpp(rng, factors, int(k), n)
+            return SubsetBatch.from_lists(subs, k_max=max(1, int(k)))
+        if len(factors) == 1:
+            subs = [sample_full_dpp(rng, np.asarray(factors[0]))
                     for _ in range(n)]
         else:
-            krondpp = KronDPP(tuple(self.factors))
+            krondpp = KronDPP(tuple(factors))
             subs = [sample_krondpp(rng, krondpp) for _ in range(n)]
         k_max = max(1, max((len(s) for s in subs), default=1))
         return SubsetBatch.from_lists(subs, k_max=k_max)
+
+    def _host_factors(self) -> Tuple[jax.Array, ...]:
+        """The factor matrices the Host oracle decomposes."""
+        return self.factors
 
     def service(self, **kwargs) -> SamplingService:
         """A micro-batching ``SamplingService`` over this model (submit /

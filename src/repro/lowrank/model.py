@@ -139,14 +139,8 @@ class LowRank(DPPModel):
     # which dispatch to the dual-space engine via the DualSpectrum's
     # sample_rows/sample_rows_kdpp hooks. Only the Host oracle needs the
     # guarded dense kernel.
-    def _sample_host(self, key: jax.Array, n: int) -> SubsetBatch:
-        from ..core.sampling import sample_full_dpp
-        seed = int(jax.random.randint(key, (), 0, np.iinfo(np.int32).max))
-        rng = np.random.default_rng(seed)
-        L = np.asarray(self.dense_kernel())
-        subs = [sample_full_dpp(rng, L) for _ in range(n)]
-        k_max = max(1, max((len(s) for s in subs), default=1))
-        return SubsetBatch.from_lists(subs, k_max=k_max)
+    def _host_factors(self) -> Tuple[jax.Array, ...]:
+        return (self.dense_kernel(),)
 
     # -- likelihood ---------------------------------------------------------
     def log_prob(self, batch: SubsetBatch,

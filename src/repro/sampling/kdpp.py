@@ -19,6 +19,11 @@ built, never the N eigenvectors — so a KronDPP k-DPP costs
 O(sum N_i^3 + N k) setup instead of O(N^3). A dense kernel is the m=1
 case (``sample_kdpp_dense``), which is what the serving layer uses for
 stochastic KV-cache eviction.
+
+Each host-level call of ``sample_kdpp_batched`` builds one ESP table (it
+depends only on the spectrum and k, and is rebuilt inside every call) and
+counts it as ``dpp.kdpp.esp_builds``; under the default ``NullTracker``
+that is a no-op.
 """
 
 from __future__ import annotations
@@ -29,6 +34,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..kernels import ops as kernel_ops
 from .batched import compact_selection, gather_factor_columns
 from .spectral import FactorSpectrum, log_product_spectrum
@@ -126,6 +132,7 @@ def sample_kdpp_batched(key: jax.Array, spectrum: FactorSpectrum, k: int,
     the single-device call bit-for-bit on shared keys.
     """
     keys = jax.random.split(key, num_samples)
+    obs.current_tracker().counter("dpp.kdpp.esp_builds")
     # duck-typed dispatch, as in sample_krondpp_batched: low-rank dual
     # spectra run the conditional draw on their r dual eigenvalues
     kdpp_hook = getattr(spectrum, "sample_rows_kdpp", None)

@@ -105,8 +105,9 @@ def test_host_runtime_matches_device_size_distribution(model):
     h = np.bincount(np.asarray(host.sizes()), minlength=N + 1) / 400
     d = np.bincount(np.asarray(dev.sizes()), minlength=N + 1)[:N + 1] / 400
     assert np.abs(h - d).max() < 0.12
-    with pytest.raises(ValueError):
-        model.sample(jax.random.PRNGKey(0), 1, k=2, runtime=dpp.Host())
+    # the Host oracle draws k-DPPs too: k distinct items in every row
+    hk = model.sample(jax.random.PRNGKey(0), 20, k=2, runtime=dpp.Host())
+    assert all(len(r) == len(set(r)) == 2 for r in hk.to_lists())
 
 
 def test_marginal_matches_bruteforce(model, oracle):

@@ -248,6 +248,50 @@ def test_null_tracker_gives_the_facade_no_spans(tmp_path, monkeypatch):
     assert spans.current_span() is None
 
 
+def test_facade_root_span_carries_k_and_kdpp_calls_count_esp_builds():
+    model = _kron_234()
+    key = jax.random.PRNGKey(9)
+    t = obs.InMemoryTracker()
+    with obs.use(t):
+        model.sample(key, 6, k=3)
+        model.sample(key, 6)
+    roots = [e for e in _span_events(t) if e["op"] == "dpp.sample"]
+    assert [r["k"] for r in roots] == [3, None]
+    assert [r["k_max"] for r in roots][0] == 3
+    assert t.counters["dpp.kdpp.esp_builds"] == 1     # the k-DPP call only
+
+
+def test_null_tracker_kdpp_sample_costs_no_span_and_no_counter(
+        tmp_path, monkeypatch):
+    """Under the default NullTracker a k-DPP call builds no span and its
+    ESP-build counter is the tracker's no-op."""
+    model = _kron_234()
+    key = jax.random.PRNGKey(5)
+    model.sample(key, 4, k=2)                    # warm: spectrum cached
+
+    def no_span(*a, **kw):
+        raise AssertionError("a Span was built under the NullTracker")
+    monkeypatch.setattr(spans, "Span", no_span)
+    counted = []
+    monkeypatch.setattr(obs.NullTracker, "counter",
+                        lambda self, name, value=1, **tags:
+                        counted.append(name))
+    assert not obs.enabled(obs.current_tracker())
+    _, host = _profiled(tmp_path, lambda: model.sample(key, 4, k=2).indices)
+    assert not [h for h in host if h[0].startswith("dpp.")]
+    assert counted.count("dpp.kdpp.esp_builds") == 1   # the no-op sink
+    n = 20_000
+    null = obs.NullTracker()
+    monkeypatch.undo()
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with spans.start_span("dpp.sample", tracker=null, rows=64, k=8):
+            pass
+        null.counter("dpp.kdpp.esp_builds")
+    per_call = (time.perf_counter() - t0) / n
+    assert per_call < 20e-6, f"k tag and counter cost {per_call*1e6:.2f}µs"
+
+
 # ---------------------------------------------------------------------------
 # the service request path
 # ---------------------------------------------------------------------------

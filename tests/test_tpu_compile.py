@@ -95,6 +95,21 @@ def test_sampling_draw_names_its_phase2_kernel(compile_tpu, one_chip):
                      r'custom_call_target="tpu_custom_call"', compiled)
 
 
+def test_kdpp_draw_compiles_with_its_phase2_kernel(compile_tpu):
+    """The jitted k-DPP draw behind ``Kron.sample(key, 64, k=8)`` at the
+    paper's size: the two N-step ESP scans and the fused phase-2 kernel,
+    named as the device-trace readers look for it."""
+    from repro.sampling.kdpp import _sample_kdpp_batched
+
+    def draw(keys, l1, l2, v1, v2):
+        return _sample_kdpp_batched(keys, (l1, l2), (v1, v2), 8)
+    compiled = compile_tpu(
+        draw, ((64, 2), jnp.uint32), ((100,), F32), ((100,), F32),
+        ((100, 100), F32), ((100, 100), F32))
+    assert re.search(r"%phase2_select_pallas[.\d]* = [^\n]*"
+                     r'custom_call_target="tpu_custom_call"', compiled)
+
+
 def test_phase2_select_dense_compiles(compile_tpu):
     """One factor (a ``Dense`` model): the leading block is a single
     row, compiled unpadded; its 600 items span five lane tiles."""
