@@ -1,18 +1,30 @@
 """Exact k-DPP sampling on the factored spectrum (Kulesza & Taskar Alg. 8).
 
-A k-DPP conditions the DPP on |Y| = k. Phase 1 becomes a sequential draw
-over the N eigenvalues using elementary symmetric polynomials (ESPs):
-processing eigenvalues from last to first, include eigenvalue n with
+A k-DPP conditions the DPP on |Y| = k. Phase 1 draws which eigenvectors
+survive using elementary symmetric polynomials (ESPs): going over the N
+eigenvalues from last to first, eigenvalue n is kept with
 
     P(include) = λ_n · e_{k-1}(λ_1..λ_{n-1}) / e_k(λ_1..λ_n),
 
-decrementing k on inclusion — exactly k eigenvectors survive. The ESP
-table e_j(λ_1..λ_n) is the O(N k) recursion e_j^n = e_j^{n-1} +
+k dropping by one on each inclusion — exactly k eigenvectors survive.
+The ESP table e_j(λ_1..λ_n) is the O(N k) recursion e_j^n = e_j^{n-1} +
 λ_n e_{j-1}^{n-1}, computed in log-space (ESPs of 10^4+ eigenvalues
-overflow float range long before N does). Phase 2 is shared with
-``batched.py``: lazy factored eigenvector gather, then one batched
-``kernels.ops.phase2_select`` call (fused Pallas kernel on TPU, jax
-reference elsewhere), so the whole thing is jit/vmap clean.
+overflow float range long before N does).
+
+The draw takes k vector passes, not N serial steps. Between two
+inclusions the count j still to keep is fixed, and item n is rejected
+with 1 - p_n = e_j^{n-1} / e_j^n (e_j^n = e_j(λ_1..λ_n)). Those factors
+telescope: below the last kept item M + 1, the next one kept is m with
+probability λ_m e_{j-1}^{m-1} / e_j^M. Each decision reads only its own
+uniform and j, so the next kept item is the largest index below the last
+kept one whose uniform falls under its keep probability at j: one masked
+compare-and-max over the N items per kept item, on a (N, k+1) table of
+every keep probability. On the same uniforms these are the serial
+scan's decisions, bit for bit.
+
+Phase 2 is shared with ``batched.py``: lazy factored eigenvector gather,
+then one batched ``kernels.ops.phase2_select`` call (fused Pallas kernel
+on TPU, jax reference elsewhere), so the whole thing is jit/vmap clean.
 
 The spectrum is factored — only the O(N) product eigenvalues are ever
 built, never the N eigenvectors — so a KronDPP k-DPP costs
@@ -64,25 +76,41 @@ def _phase1_kdpp(key: jax.Array, log_lam: jax.Array, k: int) -> jax.Array:
     the kernel has fewer than k nonzero eigenvalues (every e_k denominator
     is -inf) — an unclamped draw would degenerate to the empty mask — so
     below rank this degrades to the largest achievable size and phase 2
-    pads the remaining row slots with -1."""
+    pads the remaining row slots with -1.
+
+    Item m (0-based) is decided by ``u[N-1-m]`` against ``P[m, j]``, its
+    keep probability with j items still to keep. Trip t of k looks for the
+    largest m below the last kept item with ``u < P[m, k0 - t]``: every
+    item in between was rejected at that same count, as the serial scan
+    from m = N-1 down would reject it. A trip that finds none ends the
+    draw (``live``), since the scan would then reject every item left. The
+    count is the same in every row on a trip, so under a batch ``vmap``
+    the column of ``P`` is one unbatched slice."""
     N = log_lam.shape[0]
     table = log_esp_table(log_lam, k)
     k0 = jnp.minimum(jnp.asarray(k, jnp.int32),
                      jnp.sum(jnp.isfinite(log_lam)).astype(jnp.int32))
-    u = jax.random.uniform(key, (N,))
+    u = jax.random.uniform(key, (N,))[::-1]       # u[m] decides item m
 
-    def body(k_rem, inp):
-        n, ll, un = inp                       # n runs N..1
-        log_num = ll + table[n - 1, jnp.maximum(k_rem - 1, 0)]
-        log_den = table[n, k_rem]
-        p = jnp.exp(jnp.minimum(log_num - log_den, 0.0))
-        p = jnp.where((k_rem > 0) & jnp.isfinite(log_den), p, 0.0)
-        inc = un < p
-        return k_rem - inc.astype(k_rem.dtype), inc
+    j = jnp.arange(k + 1)
+    log_num = log_lam[:, None] + table[:-1, jnp.maximum(j - 1, 0)]
+    log_den = table[1:]
+    P = jnp.exp(jnp.minimum(log_num - log_den, 0.0))
+    P = jnp.where((j > 0) & jnp.isfinite(log_den), P, 0.0)
+    m = jnp.arange(N, dtype=jnp.int32)
 
-    ns = jnp.arange(N, 0, -1)
-    _, incs = jax.lax.scan(body, k0, (ns, log_lam[::-1], u))
-    return incs[::-1]
+    def trip(t, carry):
+        mask, limit, live = carry
+        k_rem = k0 - t
+        p = jax.lax.dynamic_index_in_dim(P, jnp.maximum(k_rem, 0), axis=1,
+                                         keepdims=False)
+        hit = jnp.max(jnp.where((u < p) & (m < limit), m, -1))
+        take = live & (k_rem > 0) & (hit >= 0)
+        return mask | (take & (m == hit)), jnp.where(take, hit, limit), take
+
+    mask, _, _ = jax.lax.fori_loop(
+        0, k, trip, (jnp.zeros((N,), bool), jnp.int32(N), jnp.bool_(True)))
+    return mask
 
 
 def _phase1_one_kdpp(key: jax.Array, lams: Tuple[jax.Array, ...],

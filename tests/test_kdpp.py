@@ -1,7 +1,8 @@
 """Exact k-DPP draws: the float64 Host oracle against brute-force
 enumeration, the Local device draws against the keyed float64 replay of
-the benchmark (``bench/kdpp_ref.py``), and the ``dpp.kdpp.esp_builds``
-counter.
+the benchmark (``bench/kdpp_ref.py``), the k-pass conditional draw
+against the N-step serial scan it replaces, and the
+``dpp.kdpp.esp_builds`` counter.
 
 A k-DPP gives P(Y) = det(L_Y) / e_k(λ) on |Y| = k. Below rank, where
 |Y| = k has probability 0, every sampler draws exactly rank items,
@@ -14,6 +15,7 @@ import pathlib
 import sys
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -21,6 +23,7 @@ import pytest
 from repro import dpp, obs
 from repro.core.sampling import log_esp_table, sample_kdpp
 from repro.sampling.kdpp import _phase1_kdpp
+from repro.sampling.kdpp import log_esp_table as log_esp_table_jax
 from repro.sampling.spectral import log_product_spectrum
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -145,3 +148,93 @@ def test_esp_builds_count_one_per_kdpp_call(kind):
     with obs.use(obs.InMemoryTracker()) as plain:
         model.sample(key, 8)
     assert "dpp.kdpp.esp_builds" not in plain.counters
+
+
+def _phase1_serial_scan(key, log_lam, k):
+    """The N-step serial scan that ``_phase1_kdpp`` replaces, kept as an
+    oracle: from the last eigenvalue to the first, item n is kept iff its
+    uniform falls under its keep probability at the count still to keep."""
+    N = log_lam.shape[0]
+    table = log_esp_table_jax(log_lam, k)
+    k0 = jnp.minimum(jnp.asarray(k, jnp.int32),
+                     jnp.sum(jnp.isfinite(log_lam)).astype(jnp.int32))
+    u = jax.random.uniform(key, (N,))
+
+    def body(k_rem, inp):
+        n, ll, un = inp                       # n runs N..1
+        log_num = ll + table[n - 1, jnp.maximum(k_rem - 1, 0)]
+        log_den = table[n, k_rem]
+        p = jnp.exp(jnp.minimum(log_num - log_den, 0.0))
+        p = jnp.where((k_rem > 0) & jnp.isfinite(log_den), p, 0.0)
+        inc = un < p
+        return k_rem - inc.astype(k_rem.dtype), inc
+
+    ns = jnp.arange(N, 0, -1)
+    _, incs = jax.lax.scan(body, k0, (ns, log_lam[::-1], u))
+    return incs[::-1]
+
+
+def _log_spectrum(kind: str) -> jax.Array:
+    if kind == "kron_1e4":          # the slate cell's kernel, N = 100 x 100
+        factors = data.kron_factors(jax.random.PRNGKey(31), (100, 100), 10.0)
+        return log_product_spectrum(tuple(dpp.Kron(factors).spectrum().lams))
+    if kind == "kron_zeros":        # N = 5 x 4 of rank 3 x 3 = 9
+        return log_product_spectrum((jnp.array([0.0, 0.5, 2.0, 0.0, 1.3]),
+                                     jnp.array([0.7, 0.0, 1.1, 3.0])))
+    if kind == "kron_small":        # N = 6 x 5 of full rank
+        return log_product_spectrum(tuple(
+            dpp.random_kron(jax.random.PRNGKey(32), (6, 5)).spectrum().lams))
+    # a LowRank dual spectrum: r = 4 dual eigenvalues of a 12-item kernel
+    V = jax.random.normal(jax.random.PRNGKey(33), (12, 4))
+    return dpp.LowRank(V, jnp.ones((12,))).spectrum().log_eigenvalues()
+
+
+@pytest.mark.parametrize("kind,k", [
+    ("kron_1e4", 8),                # many keys at the cell's size
+    ("kron_zeros", 12),             # rank 9 < k
+    ("kron_zeros", 1),
+    ("kron_zeros", 9),              # k = rank, with zero eigenvalues
+    ("kron_small", 1),
+    ("kron_small", 30),             # k = rank = N
+    ("lowrank_dual", 2),
+    ("lowrank_dual", 4),            # k = r
+])
+def test_phase1_kdpp_matches_the_serial_scan(kind, k):
+    """The k-pass draw keeps exactly the items of the serial scan, bit for
+    bit, on 64 keys vmapped as the batch path draws them."""
+    ll = _log_spectrum(kind)
+    keys = jax.random.split(jax.random.PRNGKey(34), 64)
+    new = np.asarray(jax.jit(jax.vmap(
+        lambda kk: _phase1_kdpp(kk, ll, k)))(keys))
+    old = np.asarray(jax.jit(jax.vmap(
+        lambda kk: _phase1_serial_scan(kk, ll, k)))(keys))
+    rank = int(np.isfinite(np.asarray(ll)).sum())
+    assert (old.sum(axis=1) == min(k, rank)).all()
+    np.testing.assert_array_equal(new, old)
+
+
+def _loop_lengths(jaxpr) -> list:
+    """Trip counts of every loop in a jaxpr and its sub-jaxprs (None for a
+    ``while``, whose count is not static)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scan", "while"):
+            out.append(eqn.params.get("length"))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    out += _loop_lengths(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    out += _loop_lengths(sub)
+    return out
+
+
+def test_phase1_kdpp_has_one_n_step_loop():
+    """At N = 10^4, k = 8 the only N-trip loop is the ESP table's; the
+    conditional draw is k trips, and no loop has an unknown count."""
+    N, k = 10_000, 8
+    jaxpr = jax.make_jaxpr(lambda key, ll: _phase1_kdpp(key, ll, k))(
+        jax.random.PRNGKey(0), jnp.zeros((N,)))
+    lengths = _loop_lengths(jaxpr.jaxpr)
+    assert lengths.count(N) == 1, lengths
+    assert None not in lengths and max(n for n in lengths if n != N) <= k
