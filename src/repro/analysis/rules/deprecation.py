@@ -11,13 +11,12 @@ deprecation-stacklevel
 
 deprecated-call
     The deprecated entry points — ``core.fit_krk_picard`` / ``fit_em`` /
-    ``fit_joint_picard`` / ``sample_krondpp_batch`` and the bare
-    ``sample_*`` re-exports on ``repro.sampling`` — exist so external
-    code keeps importing; in-repo code must target the engines/facade
-    they delegate to. Flagged: importing one of these names from a shim
-    module (``repro.core`` / ``repro.sampling`` or relative equivalents)
-    anywhere outside the modules that define or re-export them. Tests
-    are exempt (they pin the shims' warning behavior deliberately).
+    ``fit_joint_picard`` — exist so external code keeps importing;
+    in-repo code must target the engines/facade they delegate to.
+    Flagged: importing one of these names from the shim module
+    (``repro.core`` or relative equivalents) anywhere outside the modules
+    that define or re-export them. Tests are exempt (they pin the shims'
+    warning behavior deliberately).
 """
 
 from __future__ import annotations
@@ -32,24 +31,16 @@ _DEPRECATED = {
     "fit_krk_picard": "repro.dpp: model.fit / learning.api.fit_krk",
     "fit_em": "repro.learning engines (learning.api)",
     "fit_joint_picard": "repro.learning engines (learning.api)",
-    "sample_krondpp_batch": "repro.dpp: model.sample, or "
-                            "sampling.batched.sample_krondpp_batched",
-    "sample_krondpp_batched": "repro.dpp model.sample, or import from "
-                              "repro.sampling.batched",
-    "sample_kdpp_batched": "repro.dpp model.sample(key, n, k=k), or import "
-                           "from repro.sampling.kdpp",
-    "sample_kdpp_dense": "repro.dpp Dense(L).sample(key, k=k), or import "
-                         "from repro.sampling.kdpp",
 }
 
 #: modules whose ``from X import name`` re-export is the deprecated shim.
-#: Importing the same name from the defining submodule (sampling.batched,
-#: core.krk_picard, ...) is the sanctioned internal route and not flagged.
-_SHIM_MODULES = {"repro.core", "core", "repro.sampling", "sampling"}
+#: Importing the same name from the defining submodule (core.krk_picard,
+#: core.em, ...) is the sanctioned internal route and not flagged.
+_SHIM_MODULES = {"repro.core", "core"}
 
 #: files allowed to reference the deprecated names: definers + re-exporters
 _DEFINING_FILES = {"krk_picard.py", "em.py", "joint_picard.py",
-                   "sampling.py", "__init__.py"}
+                   "__init__.py"}
 
 
 def _module_of(node: ast.ImportFrom, parts) -> str:
@@ -106,8 +97,7 @@ def check(ctx):
 
 @register(
     "deprecated-call",
-    "no in-repo caller imports a deprecated entry point (core.fit_*, "
-    "core.sample_krondpp_batch, bare repro.sampling sample_* re-exports) "
+    "no in-repo caller imports a deprecated entry point (core.fit_*) "
     "from its shim module",
     "the shims exist for external callers; in-repo code targets the "
     "facade/engines they delegate to (scan migrated from the "
@@ -115,17 +105,12 @@ def check(ctx):
 def check_callers(ctx):
     if ctx.is_test or not in_library(ctx.parts):
         return
-    if ctx.name in _DEFINING_FILES and (
-            "core" in ctx.parts or "sampling" in ctx.parts):
+    if ctx.name in _DEFINING_FILES and "core" in ctx.parts:
         return
     for node in ast.walk(ctx.tree):
         if isinstance(node, ast.ImportFrom):
             mod = _module_of(node, ctx.parts)
-            if mod.split(".")[-1] not in {m.split(".")[-1]
-                                          for m in _SHIM_MODULES}:
-                continue
-            if mod not in _SHIM_MODULES and not any(
-                    mod.endswith("." + m) for m in ("core", "sampling")):
+            if mod not in _SHIM_MODULES and not mod.endswith(".core"):
                 continue
             for alias in node.names:
                 if alias.name in _DEPRECATED:
@@ -136,7 +121,7 @@ def check_callers(ctx):
             q = qualname(node) or ""
             parts = q.split(".")
             if len(parts) >= 2 and parts[-1] in _DEPRECATED \
-                    and parts[-2] in ("core", "sampling"):
+                    and parts[-2] == "core":
                 yield node.lineno, (
                     f"references deprecated {q!r} — use "
                     f"{_DEPRECATED[parts[-1]]}")

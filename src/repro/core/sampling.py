@@ -12,15 +12,15 @@ eagerly with numpy-style control flow; the per-step linear algebra is jax.
 .. deprecated::
     The host loop is kept as the *reference oracle* (tests validate the
     device samplers against it). Production callers should use the
-    device-resident batched subsystem in :mod:`repro.sampling`
-    (``SamplingService`` / ``sample_krondpp_batched``), which amortizes
+    ``repro.dpp`` facade (``model.sample`` / ``model.service``), whose
+    device-resident batched subsystem in :mod:`repro.sampling` amortizes
     the factor eigendecompositions and draws whole batches in one
-    jit+vmap device call; ``sample_krondpp_batch`` below delegates there.
+    jit+vmap device call.
 """
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -168,28 +168,6 @@ def sample_kdpp(rng: np.random.Generator, factors: Sequence[np.ndarray],
         out.append(_phase2_select(rng, _kron_columns(vecs, J))
                    if len(J) else [])
     return out
-
-
-def sample_krondpp_batch(key: jax.Array, dpp: KronDPP, num_samples: int,
-                         k_max: Optional[int] = None) -> List[List[int]]:
-    """Batched device sampling — delegates to the batched subsystem.
-
-    .. deprecated::
-        Use the ``repro.dpp`` facade:
-        ``Kron(factors).sample(key, num_samples)`` (one jit+vmap device
-        call, spectra amortized in the SpectralCache), or
-        ``model.service()`` for repeated micro-batched use.
-    """
-    import warnings
-    warnings.warn(
-        "core.sample_krondpp_batch is deprecated; use "
-        "repro.dpp.Kron(factors).sample(key, num_samples) instead",
-        DeprecationWarning, stacklevel=2)
-    from ..sampling.batched import picks_to_lists, sample_krondpp_batched
-    from ..sampling.spectral import default_cache
-    spec = default_cache().spectrum(dpp)
-    picks, _, _ = sample_krondpp_batched(key, spec, k_max, num_samples)
-    return picks_to_lists(picks)
 
 
 # ---------------------------------------------------------------------------

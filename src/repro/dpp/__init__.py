@@ -41,8 +41,9 @@ axes and reductions are psum'd; draws and fits reproduce ``Local`` on
 shared keys (bit-for-bit for sampling). The pre-runtime placement
 spellings — ``backend="device"|"host"`` strings, ``fit(mesh=...)``, the
 ``--distributed`` CLI flag — are DeprecationWarning shims onto runtimes,
-as are the pre-facade free functions (``core.sample_krondpp_batch``,
-``core.fit_krk_picard``, bare ``repro.sampling.sample_*``).
+as are the pre-facade ``core.fit_*`` drivers. Sampling has no such
+shims: it goes through the models here (``repro.dpp.functional`` inside
+a jit trace).
 """
 
 from ..learning import schedules

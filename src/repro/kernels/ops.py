@@ -75,25 +75,6 @@ def kron_matvec(A: jax.Array, B: jax.Array, X: jax.Array,
     return Y[:batch].reshape(batch, P1, P2)[:, :N1, :N2].reshape(batch, N1 * N2)
 
 
-def kron_eigvec_batch(P1: jax.Array, P2: jax.Array, i: jax.Array,
-                      j: jax.Array, force_pallas: bool = False) -> jax.Array:
-    """Columns of P1 ⊗ P2 at index pairs (i, j) — batched lazy eigenvector
-    assembly for the sampling subsystem. i, j: (k,) int32. Returns (N, k).
-
-    Identity: (P1 ⊗ P2) vec(e_i e_j^T) = vec(P1[:, i] P2[:, j]^T), so on
-    TPU this reuses the ``kron_matvec`` Pallas path on the one-hot batch
-    (two MXU matmuls); elsewhere the gather + outer product costs O(N k)
-    instead of the matmul route's O(N (N1+N2) k).
-    """
-    N1, N2 = P1.shape[0], P2.shape[0]
-    if _on_tpu() or force_pallas:
-        E = jnp.zeros((i.shape[0], N1 * N2), P1.dtype)
-        E = E.at[jnp.arange(i.shape[0]), i * N2 + j].set(1.0)
-        return kron_matvec(P1, P2, E, force_pallas=force_pallas).T
-    return (P1[:, i][:, None, :] * P2[:, j][None, :, :]).reshape(
-        N1 * N2, i.shape[0])
-
-
 # ---------------------------------------------------------------------------
 # phase-2 projection-DPP selection (sampling hot path)
 # ---------------------------------------------------------------------------

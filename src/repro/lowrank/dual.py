@@ -82,8 +82,9 @@ class DualSpectrum:
         return max(1, min(k, self.rank))
 
     # -- sampler dispatch hooks --------------------------------------------
-    # ``sample_krondpp_batched`` / ``_keyed`` / ``sample_kdpp_batched`` call
-    # these when present instead of assembling N-dimensional eigenvectors.
+    # ``sample_krondpp_keyed`` / ``sample_kdpp_batched`` (the two draw
+    # dispatch points) call these when present instead of assembling
+    # N-dimensional eigenvectors.
     def sample_rows(self, row_keys: jax.Array, k_max: int, backend=None,
                     runtime=None):
         from .sample import sample_dual_keyed

@@ -136,9 +136,10 @@ class LowRank(DPPModel):
 
     # -- sampling -----------------------------------------------------------
     # sample() is inherited: the base draws through the batched samplers,
-    # which dispatch to the dual-space engine via the DualSpectrum's
-    # sample_rows/sample_rows_kdpp hooks. Only the Host oracle needs the
-    # guarded dense kernel.
+    # whose dispatch points (sampling.batched.sample_krondpp_keyed,
+    # sampling.kdpp.sample_kdpp_batched) hand the draw to the dual-space
+    # engine via the DualSpectrum's sample_rows/sample_rows_kdpp hooks.
+    # Only the Host oracle needs the guarded dense kernel.
     def _host_factors(self) -> Tuple[jax.Array, ...]:
         return (self.dense_kernel(),)
 

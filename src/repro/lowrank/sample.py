@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from ..kernels.phase2_select import EPS as _EPS
 from ..kernels.phase2_select import MASS_EPS as _MASS_EPS
-from ..sampling.batched import compact_selection
+from ..sampling.batched import bernoulli_mask, draw_slots, resolve_runtime
 from ..sampling.kdpp import _phase1_kdpp
 from .dual import DualSpectrum
 
@@ -92,41 +92,31 @@ def _phase2_dual_one(us: jax.Array, phi: jax.Array, Gamma: jax.Array,
     return picks
 
 
-def _phase1_dual_one(key: jax.Array, log_lams: jax.Array, E: jax.Array,
-                     k_max: int):
-    """One draw's spectrum phase on the r dual eigenvalues."""
-    k1, k2 = jax.random.split(key)
-    u = jax.random.uniform(k1, log_lams.shape)
-    mask = u < jax.nn.sigmoid(log_lams)
-    sel, valid, truncated = compact_selection(mask, k_max)
-    k_eff = jnp.minimum(jnp.sum(mask), k_max)
+def _phase1_dual_one(key: jax.Array, mask_rule, E: jax.Array, k_slots: int):
+    """One draw's spectrum phase on the r dual eigenvalues:
+    (us, Gamma (r, k_slots), k_eff, truncated)."""
+    us, sel, valid, k_eff, truncated = draw_slots(key, mask_rule, k_slots)
     Gamma = E[:, sel] * valid[None, :].astype(E.dtype)
-    us = jax.random.uniform(k2, (k_max,))
-    return us, Gamma, k_eff.astype(jnp.int32), truncated
+    return us, Gamma, k_eff, truncated
+
+
+def _phase2_dual(us, phi, Gammas, k_eff):
+    return jax.vmap(
+        lambda u, G, ke: _phase2_dual_one(u, phi, G, ke))(us, Gammas, k_eff)
 
 
 @functools.partial(jax.jit, static_argnames=("k_max",))
 def _sample_dual(keys, phi, log_lams, E, k_max):
-    us, Gammas, k_eff, truncated = jax.vmap(
-        lambda k: _phase1_dual_one(k, log_lams, E, k_max))(keys)
-    picks = jax.vmap(
-        lambda u, G, ke: _phase2_dual_one(u, phi, G, ke))(us, Gammas, k_eff)
-    return picks, k_eff, truncated
+    us, Gammas, k_eff, truncated = jax.vmap(lambda key: _phase1_dual_one(
+        key, lambda k1: bernoulli_mask(k1, log_lams), E, k_max))(keys)
+    return _phase2_dual(us, phi, Gammas, k_eff), k_eff, truncated
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _sample_dual_kdpp(keys, phi, log_lams, E, k):
-    def one(key):
-        k1, k2 = jax.random.split(key)
-        mask = _phase1_kdpp(k1, log_lams, k)
-        sel, valid, _ = compact_selection(mask, k)
-        Gamma = E[:, sel] * valid[None, :].astype(E.dtype)
-        us = jax.random.uniform(k2, (k,))
-        return us, Gamma, jnp.sum(mask).astype(jnp.int32)
-
-    us, Gammas, k_eff = jax.vmap(one)(keys)
-    return jax.vmap(
-        lambda u, G, ke: _phase2_dual_one(u, phi, G, ke))(us, Gammas, k_eff)
+    us, Gammas, k_eff, _ = jax.vmap(lambda key: _phase1_dual_one(
+        key, lambda k1: _phase1_kdpp(k1, log_lams, k), E, k))(keys)
+    return _phase2_dual(us, phi, Gammas, k_eff)
 
 
 def sample_dual_keyed(row_keys: jax.Array, dual: DualSpectrum, k_max: int,
@@ -138,19 +128,15 @@ def sample_dual_keyed(row_keys: jax.Array, dual: DualSpectrum, k_max: int,
     with -1 padding, counts (B,) int32, truncated (B,) bool). Row i is a
     function of ``row_keys[i]`` alone (batching-invariant), so the async
     serving tier's per-(tenant, seq, row) keying reproduces draws no
-    matter how traffic coalesced. Under a mesh runtime the key batch is
-    sharded over the data axes via ``runtime.map_keys`` with the dual
+    matter how traffic coalesced. The draw runs through
+    ``runtime.map_keys`` (default ``Local()``) with the dual
     factorization flowing through operands.
     """
     _check_backend(backend)
-    phi, log_lams, E = dual.phi, dual.log_eigenvalues(), dual.basis()
-    if runtime is not None and getattr(runtime, "is_mesh", False):
-        return runtime.map_keys(
-            lambda ks, ops: _sample_dual(ks, ops[0], ops[1], ops[2],
-                                         int(k_max)),
-            row_keys, operands=(phi, log_lams, E),
-            static_key=("sample_dual", int(k_max)))
-    return _sample_dual(row_keys, phi, log_lams, E, int(k_max))
+    return resolve_runtime(runtime).map_keys(
+        lambda ks, ops: _sample_dual(ks, *ops, int(k_max)),
+        row_keys, operands=(dual.phi, dual.log_eigenvalues(), dual.basis()),
+        static_key=("sample_dual", int(k_max)))
 
 
 def sample_dual_kdpp_keyed(row_keys: jax.Array, dual: DualSpectrum, k: int,
@@ -159,11 +145,7 @@ def sample_dual_kdpp_keyed(row_keys: jax.Array, dual: DualSpectrum, k: int,
     """Exact low-rank k-DPP draws from per-row keys: (B, k) int32 picks,
     exactly min(k, dual rank) distinct items per row, -1 padded."""
     _check_backend(backend)
-    phi, log_lams, E = dual.phi, dual.log_eigenvalues(), dual.basis()
-    if runtime is not None and getattr(runtime, "is_mesh", False):
-        return runtime.map_keys(
-            lambda ks, ops: _sample_dual_kdpp(ks, ops[0], ops[1], ops[2],
-                                              int(k)),
-            row_keys, operands=(phi, log_lams, E),
-            static_key=("sample_dual_kdpp", int(k)))
-    return _sample_dual_kdpp(row_keys, phi, log_lams, E, int(k))
+    return resolve_runtime(runtime).map_keys(
+        lambda ks, ops: _sample_dual_kdpp(ks, *ops, int(k)),
+        row_keys, operands=(dual.phi, dual.log_eigenvalues(), dual.basis()),
+        static_key=("sample_dual_kdpp", int(k)))

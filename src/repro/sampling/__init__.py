@@ -15,69 +15,44 @@ spectral.py  ``FactorSpectrum`` (per-factor eigendecompositions, product
              spectrum helpers) and ``SpectralCache`` — the O(sum N_i^3)
              eigh keyed on factor identity so repeated sampling against
              one kernel pays for it once.
-batched.py   ``sample_krondpp_batched`` — phase-1 Bernoulli over the
-             factored spectrum, compaction to a static (k_max,) slot
-             array, lazy eigenvector gather, and the QR-free masked-scan
-             projection-selection loop (phase 2). Also the shared
-             fixed-shape building blocks.
-kdpp.py      ``sample_kdpp_batched`` / ``sample_kdpp_dense`` — exactly-k
-             sampling via the log-space elementary-symmetric-polynomial
-             recursion on the factored spectrum.
+batched.py   ``sample_krondpp_keyed`` — the one dispatch point of the
+             exact-DPP draw (``sample_krondpp_batched`` splits one key
+             and calls it): phase-1 Bernoulli over the factored
+             spectrum, compaction to a static (k_max,) slot array, lazy
+             eigenvector gather, and the phase-2 projection selection.
+             Also the fixed-shape building blocks every sampler shares
+             (``draw_slots``: mask -> slots + phase-2 uniforms).
+kdpp.py      ``sample_kdpp_batched`` (the k-DPP's one dispatch point) /
+             ``sample_kdpp_dense`` — exactly-k sampling via the
+             log-space elementary-symmetric-polynomial recursion on the
+             factored spectrum.
 service.py   ``SamplingService`` — micro-batching front-end (submit →
              coalesce → one vmapped device call → scatter) used via
              ``model.service()`` by the data pipeline and serving layers.
 
 Placement: every sampler and the service take ``runtime=`` (a
-``repro.dpp.runtime`` Runtime) — under ``Mesh`` the PRNG-key batch is
-sharded over the mesh's data axes with draws bit-for-bit equal to the
+``repro.dpp.runtime`` Runtime, default ``Local()``); each draw runs
+through ``runtime.map_keys``, which under ``Mesh`` shards the PRNG-key
+batch over the mesh's data axes with draws bit-for-bit equal to the
 single-device call on shared keys.
 
-The bare ``sample_*`` names re-exported here are deprecated shims; new
-code goes through ``repro.dpp`` (or ``repro.dpp.functional`` inside a jit
-trace). Subsystem-internal callers import from the submodules directly.
+The sampler entry points are not re-exported here: code goes through
+``repro.dpp`` (or ``repro.dpp.functional`` inside a jit trace), and
+subsystem-internal callers import from the submodules directly.
 """
-
-import functools as _functools
-import warnings as _warnings
 
 from .spectral import (FactorSpectrum, SpectralCache, default_cache,
                        gain_for_expected_size, log_product_spectrum,
                        rescale_expected_size)
 from .batched import compile_cache_size, picks_to_lists
-from .batched import sample_krondpp_batched as _sample_krondpp_batched
 from .kdpp import log_esp_table
-from .kdpp import (sample_kdpp_batched as _sample_kdpp_batched,
-                   sample_kdpp_dense as _sample_kdpp_dense)
 from .service import SamplingService, SampleTicket
 
-
-def _deprecated_shim(fn, facade_hint):
-    @_functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        _warnings.warn(
-            f"repro.sampling.{fn.__name__} (top-level re-export) is "
-            f"deprecated; use {facade_hint}, or import it from "
-            f"repro.sampling.{fn.__module__.rsplit('.', 1)[-1]} if you "
-            f"really want the raw engine entry point",
-            DeprecationWarning, stacklevel=2)
-        return fn(*args, **kwargs)
-    return wrapper
-
-
-sample_krondpp_batched = _deprecated_shim(
-    _sample_krondpp_batched, "repro.dpp: model.sample(key, n)")
-sample_kdpp_batched = _deprecated_shim(
-    _sample_kdpp_batched, "repro.dpp: model.sample(key, n, k=k)")
-sample_kdpp_dense = _deprecated_shim(
-    _sample_kdpp_dense,
-    "repro.dpp: Dense(L).sample(key, k=k) — or "
-    "repro.dpp.functional.sample_kdpp_dense inside a jit trace")
 
 __all__ = [
     "FactorSpectrum", "SpectralCache", "default_cache",
     "log_product_spectrum", "rescale_expected_size",
     "gain_for_expected_size",
-    "sample_krondpp_batched", "picks_to_lists", "compile_cache_size",
-    "log_esp_table", "sample_kdpp_batched", "sample_kdpp_dense",
+    "picks_to_lists", "compile_cache_size", "log_esp_table",
     "SamplingService", "SampleTicket",
 ]

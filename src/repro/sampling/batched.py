@@ -37,7 +37,6 @@ import jax.numpy as jnp
 
 from ..core.kron import split_indices_multi
 from ..kernels import ops as kernel_ops
-from ..kernels.ops import kron_eigvec_batch
 from ..kernels.phase2_select import (canonical_pair, init_norms, pad_pair,
                                      select_step)
 from .spectral import FactorSpectrum, log_product_spectrum
@@ -93,23 +92,17 @@ def assemble_eigvecs(spectrum_vecs: Tuple[jax.Array, ...],
                      valid: jax.Array) -> jax.Array:
     """Materialize the selected Kronecker eigenvectors, (N, k_max).
 
-    The batched form of ``kron.kron_eigvec``: for m=2 this is the one-hot
-    ``kron_matvec`` identity routed through ``kernels.ops`` (Pallas path
-    on TPU). The sampler itself stays in factored form
-    (``gather_factor_columns``) and never builds this matrix; this is the
-    reference assembly used by tests and by callers that want explicit
-    eigenvectors.
+    The batched form of ``kron.kron_eigvec``: the factored columns
+    (``gather_factor_columns``) folded by outer products, O(N k). The
+    sampler itself stays in factored form and never builds this matrix;
+    this is the reference assembly used by tests and by callers that want
+    explicit eigenvectors.
     """
-    parts = split_mixed_radix(sel, sizes)
-    if len(sizes) == 2:
-        V = kron_eigvec_batch(spectrum_vecs[0], spectrum_vecs[1],
-                              parts[0], parts[1])
-    else:
-        V = spectrum_vecs[0][:, parts[0]]
-        for P, p in zip(spectrum_vecs[1:], parts[1:]):
-            G = P[:, p]
-            V = (V[:, None, :] * G[None, :, :]).reshape(-1, sel.shape[0])
-    return V * valid[None, :].astype(V.dtype)
+    Gs = gather_factor_columns(spectrum_vecs, sizes, sel, valid)
+    V = Gs[0]
+    for G in Gs[1:]:
+        V = (V[:, None, :] * G[None, :, :]).reshape(-1, sel.shape[0])
+    return V
 
 
 def phase2_select_reference(us: jax.Array, Gs: Tuple[jax.Array, ...],
@@ -170,38 +163,55 @@ def phase2_select_reference(us: jax.Array, Gs: Tuple[jax.Array, ...],
     return picks[:k_max]
 
 
-def phase2_select(key: jax.Array, Gs: Tuple[jax.Array, ...],
-                  sizes: Tuple[int, ...], k_eff: jax.Array,
-                  backend: Optional[str] = None) -> jax.Array:
-    """Single-sample phase-2 selection from a PRNG key (compat surface).
-
-    Draws the per-step uniforms and dispatches through the ops-level
-    entry point (``kernels.ops.phase2_select``): fused Pallas kernel on
-    TPU, ``phase2_select_reference`` elsewhere; ``backend`` forces one.
-    """
-    us = jax.random.uniform(key, (Gs[0].shape[1],))
-    return kernel_ops.phase2_select(us, Gs, sizes, k_eff, backend=backend)
-
-
 # ---------------------------------------------------------------------------
 # The batched sampler
 # ---------------------------------------------------------------------------
+
+def bernoulli_mask(key: jax.Array, log_lam: jax.Array) -> jax.Array:
+    """The DPP's spectrum draw: eigen-index n is kept with probability
+    λ/(1+λ) = sigmoid(log λ), on the log-space fold so a huge product
+    spectrum never overflows to NaN probabilities."""
+    u = jax.random.uniform(key, log_lam.shape)
+    return u < jax.nn.sigmoid(log_lam)
+
+
+def draw_slots(key: jax.Array, mask_rule, k_slots: int):
+    """Phase 1 of one draw, shared by every sampler: the eigen-index mask
+    from ``mask_rule(subkey)`` (``bernoulli_mask``, or the k-DPP's
+    ``kdpp._phase1_kdpp``), compacted into ``k_slots`` static slots, and
+    the phase-2 uniforms, one per slot.
+
+    Returns (us (k_slots,), sel (k_slots,) int32, valid (k_slots,) bool,
+    k_eff () int32 = min(|mask|, k_slots), truncated () bool). The first
+    half of ``jax.random.split(key)`` feeds the mask and the second the
+    uniforms: every draw's picks depend on that order.
+    """
+    k1, k2 = jax.random.split(key)
+    mask = mask_rule(k1)
+    sel, valid, truncated = compact_selection(mask, k_slots)
+    k_eff = jnp.minimum(jnp.sum(mask), k_slots).astype(jnp.int32)
+    us = jax.random.uniform(k2, (k_slots,))
+    return us, sel, valid, k_eff, truncated
+
+
+def resolve_runtime(runtime):
+    """``runtime``, or the default ``Local()`` placement for None.
+    Imported at call time: ``repro.dpp`` imports this package."""
+    if runtime is None:
+        from ..dpp.runtime import default_runtime
+        return default_runtime()
+    return runtime
+
 
 def _phase1_one(key: jax.Array, lams: Tuple[jax.Array, ...],
                 vecs: Tuple[jax.Array, ...], k_max: int):
     """One sample's spectrum draw: (us, factored columns, k_eff, trunc)."""
     sizes = tuple(l.shape[0] for l in lams)
-    # inclusion prob λ/(1+λ) = sigmoid(log λ), on the log-space fold so a
-    # huge product spectrum never overflows to NaN probabilities
     ll = log_product_spectrum(lams)
-    k1, k2 = jax.random.split(key)
-    u = jax.random.uniform(k1, ll.shape)
-    mask = u < jax.nn.sigmoid(ll)
-    sel, valid, truncated = compact_selection(mask, k_max)
-    k_eff = jnp.minimum(jnp.sum(mask), k_max)
+    us, sel, valid, k_eff, truncated = draw_slots(
+        key, lambda k1: bernoulli_mask(k1, ll), k_max)
     Gs = gather_factor_columns(vecs, sizes, sel, valid)
-    us = jax.random.uniform(k2, (k_max,))
-    return us, Gs, k_eff.astype(jnp.int32), truncated
+    return us, Gs, k_eff, truncated
 
 
 @functools.partial(jax.jit, static_argnames=("k_max", "backend"))
@@ -226,38 +236,20 @@ def sample_krondpp_batched(key: jax.Array, spectrum: FactorSpectrum,
     shape reuse the executable. ``backend`` selects the phase-2 engine
     (None = auto: fused Pallas kernel on TPU, jax reference elsewhere).
 
-    ``runtime`` selects placement (``repro.dpp.runtime``): under a mesh
-    runtime the batch of PRNG keys is sharded over the data axes
-    (``runtime.map_keys``) and each shard runs this exact per-key
-    pipeline, so draws match the single-device call bit-for-bit on
-    shared keys.
+    Row i is drawn from ``jax.random.split(key, num_samples)[i]`` by
+    ``sample_krondpp_keyed``, which also takes ``runtime``.
     """
-    if k_max is None:
-        k_max = spectrum.suggested_k_max()
     keys = jax.random.split(key, num_samples)
-    # duck-typed dispatch: spectra that carry their own row sampler (the
-    # low-rank DualSpectrum) bypass the Kronecker eigenvector machinery —
-    # same (picks, counts, truncated) contract, same per-key determinism
-    rows_hook = getattr(spectrum, "sample_rows", None)
-    if rows_hook is not None:
-        return rows_hook(keys, int(k_max), backend=backend, runtime=runtime)
-    lams, vecs = tuple(spectrum.lams), tuple(spectrum.vecs)
-    if runtime is not None and getattr(runtime, "is_mesh", False):
-        # spectra flow through operands (not closures) so the mesh can
-        # cache one compiled executable per (k_max, backend) + shape
-        return runtime.map_keys(
-            lambda ks, ops: _sample_batched(ks, ops[0], ops[1],
-                                            int(k_max), backend),
-            keys, operands=(lams, vecs),
-            static_key=("sample_krondpp_batched", int(k_max), backend))
-    return _sample_batched(keys, lams, vecs, int(k_max), backend)
+    return sample_krondpp_keyed(keys, spectrum, k_max, backend=backend,
+                                runtime=runtime)
 
 
 def sample_krondpp_keyed(row_keys: jax.Array, spectrum: FactorSpectrum,
                          k_max: Optional[int] = None,
                          backend: Optional[str] = None, runtime=None
                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """``sample_krondpp_batched`` with the per-row PRNG keys supplied.
+    """``sample_krondpp_batched`` with the per-row PRNG keys supplied —
+    the one dispatch point of the exact-DPP draw.
 
     ``row_keys`` is a (num_samples, 2) uint32 key array; row i is drawn
     from ``row_keys[i]`` alone, so the result for a given key does not
@@ -269,21 +261,27 @@ def sample_krondpp_keyed(row_keys: jax.Array, spectrum: FactorSpectrum,
     Same return contract as ``sample_krondpp_batched``:
     (picks (num_samples, k_max) int32 with -1 padding, counts
     (num_samples,) int32, truncated (num_samples,) bool).
+
+    ``runtime`` selects placement (``repro.dpp.runtime``, default
+    ``Local()``): the draw runs through ``runtime.map_keys``, which under
+    a mesh shards the keys over the data axes, so draws match the
+    single-device call bit-for-bit on shared keys.
     """
     if k_max is None:
         k_max = spectrum.suggested_k_max()
+    k_max = int(k_max)
+    # duck-typed dispatch: spectra that carry their own row sampler (the
+    # low-rank DualSpectrum) bypass the Kronecker eigenvector machinery —
+    # same (picks, counts, truncated) contract, same per-key determinism
     rows_hook = getattr(spectrum, "sample_rows", None)
     if rows_hook is not None:
-        return rows_hook(row_keys, int(k_max), backend=backend,
-                         runtime=runtime)
-    lams, vecs = tuple(spectrum.lams), tuple(spectrum.vecs)
-    if runtime is not None and getattr(runtime, "is_mesh", False):
-        return runtime.map_keys(
-            lambda ks, ops: _sample_batched(ks, ops[0], ops[1],
-                                            int(k_max), backend),
-            row_keys, operands=(lams, vecs),
-            static_key=("sample_krondpp_batched", int(k_max), backend))
-    return _sample_batched(row_keys, lams, vecs, int(k_max), backend)
+        return rows_hook(row_keys, k_max, backend=backend, runtime=runtime)
+    # spectra flow through operands (not closures) so a mesh can cache
+    # one compiled executable per (k_max, backend) + shape
+    return resolve_runtime(runtime).map_keys(
+        lambda ks, ops: _sample_batched(ks, *ops, k_max, backend),
+        row_keys, operands=(tuple(spectrum.lams), tuple(spectrum.vecs)),
+        static_key=("sample_krondpp_batched", k_max, backend))
 
 
 def picks_to_lists(picks):

@@ -48,7 +48,7 @@ import jax.numpy as jnp
 
 from .. import obs
 from ..kernels import ops as kernel_ops
-from .batched import compact_selection, gather_factor_columns
+from .batched import draw_slots, gather_factor_columns, resolve_runtime
 from .spectral import FactorSpectrum, log_product_spectrum
 
 _NEG_INF = -jnp.inf
@@ -118,14 +118,11 @@ def _phase1_one_kdpp(key: jax.Array, lams: Tuple[jax.Array, ...],
     """One sample's conditional spectrum draw: (us, columns, k_eff)."""
     sizes = tuple(l.shape[0] for l in lams)
     ll = log_product_spectrum(lams)
-    k1, k2 = jax.random.split(key)
-    mask = _phase1_kdpp(k1, ll, k)
     # the ESP draw sets at most k entries, so no truncation is possible;
     # below numerical rank it sets fewer and phase 2 pads with -1
-    sel, valid, _ = compact_selection(mask, k)
-    Gs = gather_factor_columns(vecs, sizes, sel, valid)
-    us = jax.random.uniform(k2, (k,))
-    return us, Gs, jnp.sum(mask).astype(jnp.int32)
+    us, sel, valid, k_eff, _ = draw_slots(
+        key, lambda k1: _phase1_kdpp(k1, ll, k), k)
+    return us, gather_factor_columns(vecs, sizes, sel, valid), k_eff
 
 
 def _sample_one_kdpp(key: jax.Array, lams: Tuple[jax.Array, ...],
@@ -159,21 +156,17 @@ def sample_kdpp_batched(key: jax.Array, spectrum: FactorSpectrum, k: int,
     runtime the key batch is sharded over the data axes and draws match
     the single-device call bit-for-bit on shared keys.
     """
-    keys = jax.random.split(key, num_samples)
     obs.current_tracker().counter("dpp.kdpp.esp_builds")
-    # duck-typed dispatch, as in sample_krondpp_batched: low-rank dual
+    keys = jax.random.split(key, num_samples)
+    # duck-typed dispatch, as in sample_krondpp_keyed: low-rank dual
     # spectra run the conditional draw on their r dual eigenvalues
     kdpp_hook = getattr(spectrum, "sample_rows_kdpp", None)
     if kdpp_hook is not None:
         return kdpp_hook(keys, int(k), backend=backend, runtime=runtime)
-    lams, vecs = tuple(spectrum.lams), tuple(spectrum.vecs)
-    if runtime is not None and getattr(runtime, "is_mesh", False):
-        return runtime.map_keys(
-            lambda ks, ops: _sample_kdpp_batched(ks, ops[0], ops[1],
-                                                 int(k), backend),
-            keys, operands=(lams, vecs),
-            static_key=("sample_kdpp_batched", int(k), backend))
-    return _sample_kdpp_batched(keys, lams, vecs, int(k), backend)
+    return resolve_runtime(runtime).map_keys(
+        lambda ks, ops: _sample_kdpp_batched(ks, *ops, int(k), backend),
+        keys, operands=(tuple(spectrum.lams), tuple(spectrum.vecs)),
+        static_key=("sample_kdpp_batched", int(k), backend))
 
 
 def sample_kdpp_dense(key: jax.Array, L: jax.Array, k: int) -> jax.Array:

@@ -346,26 +346,6 @@ def test_core_fit_shims_warn():
         fit_em(krondpp.full_matrix(), batch, iters=1)
 
 
-def test_core_sampling_shim_warns():
-    from repro.core import sample_krondpp_batch
-    krondpp, _ = _tiny_fit_inputs()
-    with pytest.warns(DeprecationWarning, match="repro.dpp"):
-        sample_krondpp_batch(jax.random.PRNGKey(0), krondpp, 2)
-
-
-def test_sampling_toplevel_shims_warn():
-    import repro.sampling as sampling
-    krondpp, _ = _tiny_fit_inputs()
-    spec = dpp.SpectralCache().spectrum(krondpp)
-    with pytest.warns(DeprecationWarning, match="repro.dpp"):
-        sampling.sample_krondpp_batched(jax.random.PRNGKey(0), spec, 4, 2)
-    with pytest.warns(DeprecationWarning, match="repro.dpp"):
-        sampling.sample_kdpp_batched(jax.random.PRNGKey(0), spec, 2, 2)
-    with pytest.warns(DeprecationWarning, match="repro.dpp"):
-        sampling.sample_kdpp_dense(jax.random.PRNGKey(0),
-                                   krondpp.full_matrix(), 2)
-
-
 def test_facade_paths_do_not_warn():
     """The facade must not route through its own deprecated shims."""
     m = dpp.random_kron(jax.random.PRNGKey(0), (2, 3))

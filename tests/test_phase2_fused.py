@@ -19,8 +19,7 @@ from repro.core import KronDPP, random_krondpp
 from repro.kernels import ops
 from repro.sampling import SpectralCache
 from repro.sampling.batched import (_phase1_one, gather_factor_columns,
-                                    phase2_select, picks_to_lists,
-                                    sample_krondpp_batched)
+                                    picks_to_lists, sample_krondpp_batched)
 from repro.sampling.kdpp import sample_kdpp_batched
 
 pallas = pytest.mark.pallas
@@ -154,9 +153,10 @@ def test_degenerate_columns_early_exit_no_duplicates(backend):
     valid = jnp.asarray([True, True, True, True])
     Gs = gather_factor_columns(spec.vecs, (3, 4), sel, valid)
     for seed in range(6):
-        picks = np.asarray(phase2_select(jax.random.PRNGKey(seed), Gs,
-                                         (3, 4), jnp.asarray(4, jnp.int32),
-                                         backend=backend))
+        us = jax.random.uniform(jax.random.PRNGKey(seed), (4,))
+        picks = np.asarray(ops.phase2_select(us, Gs, (3, 4),
+                                             jnp.asarray(4, jnp.int32),
+                                             backend=backend))
         real = picks[picks >= 0]
         assert len(real) <= 3, picks                    # span exhausted
         assert len(set(real.tolist())) == len(real), picks

@@ -21,14 +21,15 @@ import numpy as np
 import pytest
 
 from repro import dpp, obs
-from repro.core import KronDPP, random_krondpp, sample_krondpp_batch
+from repro.core import KronDPP, random_krondpp
 from repro.core.dpp import marginal_kernel
 from repro.sampling import (FactorSpectrum, SamplingService, SpectralCache,
                             compile_cache_size, log_esp_table,
                             picks_to_lists)
-# engine entry points, imported from the submodules (the top-level
-# re-exports are deprecated shims onto the repro.dpp facade)
-from repro.sampling.batched import sample_krondpp_batched
+# engine entry points live in the submodules (repro.sampling does not
+# re-export them; consumers go through the repro.dpp facade)
+from repro.sampling.batched import (sample_krondpp_batched,
+                                    sample_krondpp_keyed)
 from repro.sampling.kdpp import sample_kdpp_batched, sample_kdpp_dense
 
 
@@ -149,7 +150,7 @@ def test_huge_spectrum_no_float32_overflow():
 
 def test_factored_columns_match_materialized_eigvecs():
     """phase 2 runs on factored columns; they must reproduce the
-    materialized Kronecker eigenvectors (kron_eigvec_batch identity)."""
+    materialized Kronecker eigenvectors (assemble_eigvecs)."""
     from repro.kernels.phase2_select import (_dot_nt, canonical_pair,
                                              init_norms, pad_pair)
     from repro.sampling.batched import assemble_eigvecs, gather_factor_columns
@@ -398,6 +399,33 @@ def test_mesh_sample_hits_the_memo_subprocess():
     assert "MESH_MEMO_OK" in out.stdout
 
 
+def _entry_model(kind):
+    if kind == "kron34":
+        return dpp.random_kron(jax.random.PRNGKey(5), (3, 4)).rescale(3.0)
+    if kind == "kron232":
+        return dpp.random_kron(jax.random.PRNGKey(8), (2, 3, 2)).rescale(3.0)
+    if kind == "dense":
+        return dpp.from_kernel(dpp.random_kron(
+            jax.random.PRNGKey(5), (3, 5)).dense_kernel()).rescale(3.0)
+    V = jax.random.normal(jax.random.PRNGKey(6), (20, 6)) * 0.6
+    return dpp.LowRank(V, jnp.abs(jax.random.normal(
+        jax.random.PRNGKey(7), (20,))) + 0.5)
+
+
+@pytest.mark.parametrize("kind", ["kron34", "kron232", "dense", "lowrank"])
+def test_batched_draw_is_keyed_draw_on_split_keys(kind):
+    """``sample_krondpp_batched(key, .., n)`` is the keyed draw on
+    ``split(key, n)``, row for row, for every spectrum family (the
+    low-rank one through its ``sample_rows`` hook)."""
+    spec = _entry_model(kind).spectrum()
+    key, n, k_max = jax.random.PRNGKey(3), 11, 5
+    got = sample_krondpp_batched(key, spec, k_max, n)
+    want = sample_krondpp_keyed(jax.random.split(key, n), spec, k_max)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert (np.asarray(got[1]) > 0).any()          # not all-empty draws
+
+
 def test_one_compile_per_shape():
     c0 = compile_cache_size()
     if c0 < 0:
@@ -477,15 +505,6 @@ def test_service_kdpp_exact_k():
     svc = SamplingService(m, seed=1)
     rows = svc.sample_kdpp(3, num_samples=5)
     assert len(rows) == 5 and all(len(set(r)) == 3 for r in rows)
-
-
-def test_core_delegate_matches_subsystem_shapes():
-    m = random_krondpp(jax.random.PRNGKey(0), (2, 3))
-    with pytest.warns(DeprecationWarning):
-        rows = sample_krondpp_batch(jax.random.PRNGKey(0), m, 6)
-    assert len(rows) == 6
-    for r in rows:
-        assert all(0 <= i < 6 for i in r) and len(set(r)) == len(r)
 
 
 # ---------------------------------------------------------------------------
