@@ -200,10 +200,12 @@ def _replay_cdf(key, b, lams, vecs, t_div, i, *, rows: int, k_max: int,
     inverse-CDF target. One compiled program, no op-by-op dispatch."""
     from repro.kernels import phase2_select as p2
     from repro.sampling.batched import _phase1_one
-    from repro.sampling.kdpp import _phase1_one_kdpp
+    from repro.sampling.kdpp import _phase1_one_kdpp, kdpp_keep_table
+    from repro.sampling.spectral import log_product_spectrum
     key = jax.random.split(key, rows)[b]
     if kdpp:
-        us, Gs, _ = _phase1_one_kdpp(key, lams, vecs, k_max)
+        P, k0 = kdpp_keep_table(log_product_spectrum(lams), k_max)
+        us, Gs, _ = _phase1_one_kdpp(key, vecs, P, k0, k_max)
     else:
         us, Gs, _, _ = _phase1_one(key, lams, vecs, k_max)
     G1, Gr = p2.canonical_pair(Gs)

@@ -25,7 +25,8 @@ batched.py   ``sample_krondpp_keyed`` — the one dispatch point of the
 kdpp.py      ``sample_kdpp_batched`` (the k-DPP's one dispatch point) /
              ``sample_kdpp_dense`` — exactly-k sampling via the
              log-space elementary-symmetric-polynomial recursion on the
-             factored spectrum.
+             factored spectrum; its keep-probability table is built
+             once per (spectrum, k) and memoized on the spectrum.
 service.py   ``SamplingService`` — micro-batching front-end (submit →
              coalesce → one vmapped device call → scatter) used via
              ``model.service()`` by the data pipeline and serving layers.

@@ -57,6 +57,41 @@ class _SizeMoments:
         self.value: Optional[Tuple[float, float]] = None  #: guarded-by: lock
 
 
+#: (k, placement) pairs whose k-DPP keep-probability table one spectrum
+#: holds, least recently used out first. An entry is an (N, k+1) float32
+#: table: 360 KB at N = 10^4, k = 8.
+KEEP_TABLES_MAX = 4
+
+
+class _KeepTables:
+    """Memo of one spectrum's k-DPP keep-probability tables
+    (``sampling.kdpp.kdpp_keep_table``). Shared like ``_SizeMoments``;
+    the lock makes concurrent first callers of one key build it once. A
+    table that is a tracer (built under an outer trace) is handed back and
+    not stored."""
+
+    __slots__ = ("lock", "tables")
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.tables = collections.OrderedDict()  #: guarded-by: lock
+
+    def get(self, key, build):
+        """(table for ``key``, whether ``build()`` made it on this call)."""
+        with self.lock:
+            table = self.tables.get(key)
+            if table is not None:
+                self.tables.move_to_end(key)
+                return table, False
+            table = build()
+            if not any(isinstance(x, jax.core.Tracer)
+                       for x in jax.tree_util.tree_leaves(table)):
+                self.tables[key] = table
+                while len(self.tables) > KEEP_TABLES_MAX:
+                    self.tables.popitem(last=False)
+            return table, True
+
+
 @dataclasses.dataclass(frozen=True)
 class FactorSpectrum:
     """Per-factor eigendecompositions of L = L_1 ⊗ ... ⊗ L_m.
@@ -66,11 +101,18 @@ class FactorSpectrum:
 
     The product spectrum {prod_i lams[i][g_i]} is only ever materialized as
     an O(N) vector; the N eigenvectors are assembled lazily per sample.
+
+    Two memos ride on the spectrum, shared by its placed copies
+    (``dataclasses.replace``) and empty in a spectrum ``SpectralCache``
+    rebuilds: the size moments (``size_moments``) and, per k, the k-DPP
+    keep-probability table (``kdpp_keep_table``).
     """
     lams: Tuple[jax.Array, ...]
     vecs: Tuple[jax.Array, ...]
     _moments: _SizeMoments = dataclasses.field(
         default_factory=_SizeMoments, compare=False, repr=False)
+    _keep_tables: _KeepTables = dataclasses.field(
+        default_factory=_KeepTables, compare=False, repr=False)
 
     @property
     def m(self) -> int:
@@ -128,6 +170,21 @@ class FactorSpectrum:
             else:
                 tracker.counter("spectral_cache.moments_hits")
             return memo.value
+
+    def kdpp_keep_table(self, k: int):
+        """``((P, k0), built)``: k-DPP phase 1's keep-probability table at
+        ``k`` (``sampling.kdpp.kdpp_keep_table``) and whether this call
+        built it. The first call at a k dispatches one jitted build and
+        does not wait for it; later calls on this spectrum or its placed
+        copies read the memo. The table lives where ``lams`` live, so each
+        placement (one device, or replicated over a mesh) builds its own
+        once. Under an outer trace the build is traced and not kept."""
+        from .kdpp import keep_table_of      # kdpp imports this module
+        k = int(k)
+        # a tracer has no sharding: its table is a tracer too, never kept
+        placement = getattr(self.lams[0], "sharding", None)
+        return self._keep_tables.get(
+            (k, placement), lambda: keep_table_of(tuple(self.lams), k))
 
     def suggested_k_max(self, num_std: float = 6.0) -> int:
         """Static phase-2 budget: E|Y| + num_std·σ, clamped to [1, N].

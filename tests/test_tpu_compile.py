@@ -95,17 +95,24 @@ def test_sampling_draw_names_its_phase2_kernel(compile_tpu, one_chip):
                      r'custom_call_target="tpu_custom_call"', compiled)
 
 
-def test_kdpp_draw_compiles_with_its_phase2_kernel(compile_tpu):
-    """The jitted k-DPP draw behind ``Kron.sample(key, 64, k=8)`` at the
-    paper's size: the two N-step ESP scans and the fused phase-2 kernel,
-    named as the device-trace readers look for it."""
-    from repro.sampling.kdpp import _sample_kdpp_batched
+def test_kdpp_draw_compiles_with_its_phase2_kernel(compile_tpu, one_chip):
+    """The two programs behind ``Kron.sample(key, 64, k=8)`` at the
+    paper's size: the keep-probability table's N-step ESP scan, built
+    once per (spectrum, k), and the draw that takes the (N, k+1) table
+    as an operand (the k-trip conditional draw, then the fused phase-2
+    kernel, named as the device-trace readers look for it)."""
+    from repro.sampling.kdpp import _sample_kdpp_batched, keep_table_of
+    N, k = 100 * 100, 8
+    table = jax.jit(lambda l1, l2: keep_table_of((l1, l2), k)).lower(
+        *[jax.ShapeDtypeStruct((100,), F32, sharding=one_chip)] * 2
+    ).compile()
+    assert [tuple(o.shape) for o in table.out_info] == [(N, k + 1), ()]
 
-    def draw(keys, l1, l2, v1, v2):
-        return _sample_kdpp_batched(keys, (l1, l2), (v1, v2), 8)
+    def draw(keys, v1, v2, P, k0):
+        return _sample_kdpp_batched(keys, (v1, v2), P, k0, k)
     compiled = compile_tpu(
-        draw, ((64, 2), jnp.uint32), ((100,), F32), ((100,), F32),
-        ((100, 100), F32), ((100, 100), F32))
+        draw, ((64, 2), jnp.uint32), ((100, 100), F32), ((100, 100), F32),
+        ((N, k + 1), F32), ((), I32))
     assert re.search(r"%phase2_select_pallas[.\d]* = [^\n]*"
                      r'custom_call_target="tpu_custom_call"', compiled)
 
